@@ -33,7 +33,7 @@ import numpy as np
 from repro.core import simulator as S
 
 
-def _placements(count: int) -> list:
+def candidate_placements(count: int) -> list:
     """``count`` distinct placements of the document workflow: rotate the
     platform of one middle step through the paper's platform set."""
     base = S.document_workflow_fig4()
@@ -52,7 +52,7 @@ def main(
 ) -> dict:
     if quick:
         n, n_placements, seeds = 128, 8, (0, 1, 2, 3)
-    placements = _placements(n_placements)
+    placements = candidate_placements(n_placements)
     spec = S.ExperimentSpec(placements[0], n_requests=n, seeds=tuple(seeds))
     rows = {
         "n_requests": float(n),

@@ -111,6 +111,9 @@ def main(argv=None) -> None:
     root = os.path.join(os.path.dirname(__file__), "..")
     sys.path.insert(0, os.path.join(root, "src"))
     sys.path.insert(0, root)  # `benchmarks` as a package from anywhere
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         adapt_bench,
         dag_overlap,

@@ -7,6 +7,12 @@ batching. The placement optimizer decides whether decode should run on the
 pod holding the cache (function shipping, §4.3/§5.3).
 
     PYTHONPATH=src python examples/federated_serving.py
+
+``main(cfg, ...)`` serves any decoder config (``chip_smoke.py`` runs it at
+the full width of qwen3-1.7b); with no arguments it runs the reduced
+smoke config. Both step programs are compiled ahead of the requests, so
+compile seconds are reported apart from time to first token (TTFT) and
+decode-step time.
 """
 
 import os
@@ -33,10 +39,21 @@ from repro.models import model as M
 from repro.serving import Request, ServingEngine, pad_cache
 
 
-def main():
-    cfg = smoke_config("qwen3-1.7b")
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    MAXLEN = 64
+WORKFLOW_REQUESTS, ENGINE_REQUESTS = 3, 6
+
+
+def main(cfg=None, params=None, prompt_len=8, new_tokens=8, seed=0):
+    """Serve ``WORKFLOW_REQUESTS`` through the two-platform workflow and
+    ``ENGINE_REQUESTS`` through the continuous-batching engine. ``params``
+    defaults to ``M.init_params(cfg, PRNGKey(seed))``. Returns the
+    measurements: compile seconds, and per request TTFT, decode-step
+    seconds and tokens."""
+    cfg = cfg or smoke_config("qwen3-1.7b")
+    if params is None:
+        params = M.init_params(cfg, jax.random.PRNGKey(seed))
+    max_len = prompt_len + new_tokens
+    vocab_hi = min(cfg.vocab_size, 200)
+    out = {"workflow": [], "engine": []}
 
     reg = PlatformRegistry()
     reg.register(Platform("prefill-pod", "us-east", native_prefetch=True))
@@ -44,19 +61,39 @@ def main():
     with Deployment(reg) as dep:
         dep.store.network.set_link("us-east", "us-west", 0.02, 200e6)
 
-        _prefill = jax.jit(lambda p, b: M.prefill(cfg, p, b))
-        _decode = jax.jit(lambda p, t, c, i: M.decode_step(cfg, p, t, c, i))
+        # compile both step programs ahead of traffic: the requests below
+        # run the compiled executables, so no request pays a compile
+        t0 = time.perf_counter()
+        tok_spec = {"tokens": jax.ShapeDtypeStruct((1, prompt_len), jnp.int32)}
+        _prefill = (
+            jax.jit(lambda p, b: M.prefill(cfg, p, b)).lower(params, tok_spec).compile()
+        )
+        cache_spec = M.spec_structs(M.cache_defs(cfg, 1, max_len))
+        _decode = (
+            jax.jit(lambda p, t, c, i: M.decode_step(cfg, p, t, c, i))
+            .lower(
+                params,
+                jax.ShapeDtypeStruct((1, 1), jnp.int32),
+                cache_spec,
+                jax.ShapeDtypeStruct((), jnp.int32),
+            )
+            .compile()
+        )
+        out["compile_s"] = time.perf_counter() - t0
 
         def prefill_fn(payload, data):
             prompt = payload
             logits, caches = _prefill(params, {"tokens": jnp.asarray(prompt)[None]})
-            caches = pad_cache(caches, MAXLEN, len(prompt), cfg=cfg)
+            first_tok = int(jnp.argmax(logits[0]))
+            t_first = time.perf_counter()
+            caches = pad_cache(caches, max_len, len(prompt), cfg=cfg)
             key = f"kv/{hash(prompt.tobytes()) & 0xFFFF}"
             dep.store.put(
                 key, jax.tree_util.tree_map(np.asarray, caches), region="us-east"
             )
             return {
-                "first_tok": int(jnp.argmax(logits[0])),
+                "first_tok": first_tok,
+                "t_first": t_first,
                 "kv_key": key,
                 "pos": len(prompt),
             }
@@ -65,8 +102,9 @@ def main():
             host_caches, _ = dep.store.get(payload["kv_key"], "us-west")
             caches = jax.tree_util.tree_map(jnp.asarray, host_caches)
             tok, cur = payload["first_tok"], payload["pos"]
-            toks = [tok]
-            for _ in range(7):
+            toks, step_s = [tok], []
+            for _ in range(new_tokens - 1):
+                t = time.perf_counter()
                 logits, caches = _decode(
                     params,
                     jnp.asarray([[tok]], jnp.int32),
@@ -74,9 +112,10 @@ def main():
                     jnp.asarray(cur, jnp.int32),
                 )
                 tok = int(jnp.argmax(logits[0]))
+                step_s.append(time.perf_counter() - t)
                 toks.append(tok)
                 cur += 1
-            return toks
+            return {"tokens": toks, "t_first": payload["t_first"], "step_s": step_s}
 
         dep.deploy("prefill", prefill_fn, ["prefill-pod"])
         dep.deploy("decode", decode_fn, ["prefill-pod", "decode-pod"])
@@ -101,31 +140,70 @@ def main():
         )
 
         # --- run a few requests through the disaggregated workflow ----------
-        rng = np.random.default_rng(0)
-        for i in range(3):
-            prompt = rng.integers(1, 200, size=8).astype(np.int32)
+        rng = np.random.default_rng(seed)
+        for i in range(WORKFLOW_REQUESTS):
+            prompt = rng.integers(1, vocab_hi, size=prompt_len).astype(np.int32)
+            t_req = time.perf_counter()
             r = dep.run(placed, prompt)
-            print(f"req {i}: {r.total_s * 1e3:7.1f} ms tokens={r.outputs}")
-
-        # --- same model under the continuous-batching engine -----------------
-        print("\ncontinuous batching on one pod:")
-        eng = ServingEngine(cfg, params, max_batch=3, max_len=MAXLEN)
-        for i in range(6):
-            eng.submit(
-                Request(
-                    i, rng.integers(1, 200, size=6).astype(np.int32), max_new_tokens=6
-                )
+            res = r.outputs
+            rec = {
+                "ttft_s": res["t_first"] - t_req,
+                "decode_step_s": float(np.mean(res["step_s"] or [0.0])),
+                "tokens": res["tokens"],
+                "total_s": r.total_s,
+            }
+            out["workflow"].append(rec)
+            print(
+                f"req {i}: {r.total_s * 1e3:7.1f} ms  "
+                f"TTFT {rec['ttft_s'] * 1e3:7.1f} ms  "
+                f"decode step {rec['decode_step_s'] * 1e3:6.2f} ms  "
+                f"tokens={res['tokens'][:8]}"
             )
-        t0 = time.perf_counter()
-        stats = eng.run()
-        dt = time.perf_counter() - t0
-        print(
-            f"  {stats['done']} requests in {dt * 1e3:.0f} ms "
-            f"({stats['decode_steps']} decode steps, "
-            f"{stats['prefills']} prefills, mean TTFT "
-            f"{np.mean(stats['ttft_s']) * 1e3:.0f} ms)"
+
+    # --- same model under the continuous-batching engine ---------------------
+    print("\ncontinuous batching on one pod:")
+    eng = ServingEngine(cfg, params, max_batch=3, max_len=max_len)
+    t0 = time.perf_counter()
+    eng.prewarm(prompt_len)
+    out["engine_compile_s"] = time.perf_counter() - t0
+    reqs = [
+        Request(
+            i,
+            rng.integers(1, vocab_hi, size=prompt_len).astype(np.int32),
+            max_new_tokens=new_tokens,
         )
+        for i in range(ENGINE_REQUESTS)
+    ]
+    t0 = time.perf_counter()
+    for req in reqs:
+        req.t_submit = t0
+        eng.submit(req)
+    stats = eng.run()
+    dt = time.perf_counter() - t0
+    for req in reqs:
+        rec = {
+            "ttft_s": req.t_first_token - req.t_submit,
+            "decode_step_s": (req.t_done - req.t_first_token)
+            / max(len(req.tokens) - 1, 1),
+            "tokens": req.tokens,
+        }
+        out["engine"].append(rec)
+        print(
+            f"  req {req.rid}: TTFT {rec['ttft_s'] * 1e3:7.1f} ms  "
+            f"decode step {rec['decode_step_s'] * 1e3:6.2f} ms  "
+            f"({len(req.tokens)} tokens)"
+        )
+    print(
+        f"  {stats['done']} requests in {dt * 1e3:.0f} ms "
+        f"({stats['decode_steps']} decode steps, "
+        f"{stats['prefills']} prefills, mean TTFT "
+        f"{np.mean(stats['ttft_s']) * 1e3:.0f} ms)"
+    )
+    return out
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

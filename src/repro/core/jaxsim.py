@@ -11,7 +11,7 @@ Python loop.
 
 The model is EXACTLY the numpy-vectorized path's
 (``_run_graph_vectorized``), arithmetic mirrored operation for operation in
-float64 (``enable_x64`` is scoped to this module's calls; the ambient jax
+float64 (``jax.enable_x64`` is scoped to this module's calls; the ambient jax
 config stays untouched), so at sigma=0 — where no randomness survives —
 all backends agree to 1e-9. With spread, this backend has its own
 draw-order contract: ``jax.random.PRNGKey(seed)`` splits into three
@@ -59,7 +59,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.kernels.cold_scan import cold_scan_parallel
 from repro.kernels.ops import cold_scan as cold_scan_kernel
@@ -539,7 +538,7 @@ def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
     # whole-object scan — first == last there, so the tail never binds
     use_stream = stream is not None and stream.chunks > 1
     use_faults = faults is not None and bool(faults)
-    with enable_x64():
+    with jax.enable_x64(True):
         placed, sigmas, graph, fault_failed = _build(
             sim, order, step_sets, preds, succs, t0s, drift, dtype,
             stream=stream, faults=faults, retry=retry,

@@ -1,9 +1,3 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax<0.5 names the TPU compiler params TPUCompilerParams; newer releases
-# renamed it CompilerParams. Resolved once here for every kernel module.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or \
-    _pltpu.TPUCompilerParams

@@ -17,12 +17,15 @@ Two device implementations of the same recurrence:
                         diagonal across the batch axis (independent rows),
                         so the kernel streams (time-chunk x batch-block)
                         tiles through VMEM — grid (batch-block, time-chunk)
-                        with time sequential, carrying ``last`` in f32
-                        scratch; within a chunk the scan is a fori_loop over
-                        rows, each step a (block_b,)-wide VPU vector op
-                        (the rglru/ssd scan shape). Time is the sublane
-                        dimension so the per-step store is a full lane row.
-                        On non-TPU backends it runs in interpret mode.
+                        with time sequential, carrying the previous mask
+                        row in int32 scratch; within a chunk the scan is a
+                        fori_loop over rows, each step a (1, block_b) VPU
+                        select (the rglru scan shape). Both gaps are
+                        compared before the kernel, in the inputs' dtype,
+                        so the kernel selects bits and never rounds a time.
+                        Time is the sublane dimension so the per-step store
+                        is a full lane row. On non-TPU backends it runs in
+                        interpret mode.
 
 ``cold_scan_parallel``  the same mask with the sequential dependence
                         factored out, for XLA on any backend: mask[k] is a
@@ -56,28 +59,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
 
-
-def _kernel(kw_ref, t0_ref, warm_ref, cold_ref, mask_ref, last_scr, *, chunk):
+def _kernel(code_ref, mask_ref, prev_scr, *, chunk):
     ic = pl.program_id(1)
 
     @pl.when(ic == 0)
     def _init():
-        last_scr[...] = jnp.full_like(last_scr, -jnp.inf)
+        prev_scr[...] = jnp.zeros_like(prev_scr)
 
-    kw = kw_ref[0]
-    t0 = t0_ref[...]  # (chunk, 1)
-    warm = warm_ref[...]  # (chunk, block_b)
-    cold = cold_ref[...]  # (chunk, block_b)
+    def body(t, prev):
+        # rows are read and written through the refs (one (1, block_b)
+        # sublane row per step): Mosaic lowers no dynamic index into a
+        # loaded value
+        code = code_ref[pl.ds(t, 1), :]
+        m = jnp.where(prev > 0, code >> 1, code & 1)
+        mask_ref[pl.ds(t, 1), :] = m
+        return m
 
-    def body(t, last):
-        m = (t0[t, 0] - last) > kw  # (block_b,)
-        last = jnp.where(m, cold[t], warm[t])
-        mask_ref[t, :] = m.astype(mask_ref.dtype)
-        return last
+    prev_scr[...] = jax.lax.fori_loop(0, chunk, body, prev_scr[...])
 
-    last_scr[...] = jax.lax.fori_loop(0, chunk, body, last_scr[...])
+
+def _gap_bits(t0, warm_end, cold_end, keep_warm):
+    """Per-request bools along the last axis: ``warm_bit[k]`` says request
+    k finds its instance cold if request k-1 ended warm, ``cold_bit[k]``
+    if it ended cold. Request 0 measures against ``last = -inf``: cold
+    under either hypothesis unless ``keep_warm`` is inf. Both gaps are
+    compared in the inputs' own dtype, so every consumer of the bits is
+    exact in any dtype."""
+    t0, warm_end, cold_end = jnp.broadcast_arrays(t0, warm_end, cold_end)
+    warm_gap = t0[..., 1:] - warm_end[..., :-1] > keep_warm
+    cold_gap = t0[..., 1:] - cold_end[..., :-1] > keep_warm
+    first = jnp.broadcast_to(keep_warm < jnp.inf, t0[..., :1].shape)
+    return (
+        jnp.concatenate([first, warm_gap], axis=-1),
+        jnp.concatenate([first, cold_gap], axis=-1),
+    )
 
 
 def cold_scan(
@@ -86,39 +102,34 @@ def cold_scan(
     """Boolean cold mask, request-major. ``t0``: (T,) arrival times shared
     by every row; ``warm_end``/``cold_end``: (B, T) per-row end times under
     the warm / cold hypothesis; ``keep_warm``: scalar idle horizon (may be
-    +inf: never cold). Returns (B, T) bool. Computed in f32 (TPU-native);
-    exact since only comparisons and selects touch the values."""
+    +inf: never cold). Returns (B, T) bool. The gaps are compared in the
+    inputs' dtype outside the kernel (``_gap_bits``), packed as the code
+    ``warm_bit | cold_bit << 1``; the kernel only selects bits, so the
+    mask is exact for f32 and f64 inputs alike."""
     B, T = warm_end.shape
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    warm_bit, cold_bit = _gap_bits(t0, warm_end, cold_end, keep_warm)
+    code = warm_bit.astype(jnp.int32) | (cold_bit.astype(jnp.int32) << 1)
     # pad to tile multiples; the scan runs forward so padded time steps
     # never influence real outputs, and padded rows are sliced away
     Tp = -(-T // chunk) * chunk
     Bp = -(-B // block_b) * block_b
-    f32 = jnp.float32
-    t0p = jnp.zeros((Tp, 1), f32).at[:T, 0].set(t0.astype(f32))
-    wp = jnp.zeros((Tp, Bp), f32).at[:T, :B].set(warm_end.astype(f32).T)
-    cp = jnp.zeros((Tp, Bp), f32).at[:T, :B].set(cold_end.astype(f32).T)
-    kw = jnp.asarray(keep_warm, f32).reshape(1)
+    codep = jnp.zeros((Tp, Bp), jnp.int32).at[:T, :B].set(code.T)
 
     kernel = functools.partial(_kernel, chunk=chunk)
     mask = pl.pallas_call(
         kernel,
         grid=(Bp // block_b, Tp // chunk),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((chunk, 1), lambda b, c: (c, 0)),
-            pl.BlockSpec((chunk, block_b), lambda b, c: (c, b)),
-            pl.BlockSpec((chunk, block_b), lambda b, c: (c, b)),
-        ],
+        in_specs=[pl.BlockSpec((chunk, block_b), lambda b, c: (c, b))],
         out_specs=pl.BlockSpec((chunk, block_b), lambda b, c: (c, b)),
-        out_shape=jax.ShapeDtypeStruct((Tp, Bp), f32),
-        scratch_shapes=[pltpu.VMEM((block_b,), f32)],
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((Tp, Bp), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, block_b), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(kw, t0p, wp, cp)
-    return mask[:T, :B].T > 0.5
+    )(codep)
+    return mask[:T, :B].T > 0
 
 
 def cold_scan_parallel(t0, warm_end, cold_end, keep_warm):
@@ -139,13 +150,9 @@ def cold_scan_parallel(t0, warm_end, cold_end, keep_warm):
     batched analogue of the numpy scan walking only its candidate list).
     Under ``vmap`` the gate becomes "any lane still flipping", so batch
     members that converge early ride along for free."""
-    t0, warm_end, cold_end = jnp.broadcast_arrays(t0, warm_end, cold_end)
-    warm_gap = t0[..., 1:] - warm_end[..., :-1] > keep_warm
-    cold_gap = t0[..., 1:] - cold_end[..., :-1] > keep_warm
-    # request 0 measures against last = -inf: cold unless keep_warm is inf
-    first = jnp.broadcast_to(keep_warm < jnp.inf, t0[..., :1].shape)
-    a = jnp.concatenate([first, warm_gap], axis=-1)
-    b = jnp.concatenate([jnp.zeros_like(first), warm_gap & ~cold_gap], axis=-1)
+    warm_bit, cold_bit = _gap_bits(t0, warm_end, cold_end, keep_warm)
+    a = warm_bit
+    b = warm_bit & ~cold_bit
     n = a.shape[-1]
     idx = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
 

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -50,9 +48,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(reachable)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)        # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)        # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)        # (bk, d)
+        q = q_ref[...].astype(jnp.float32)               # (bq, d)
+        k = k_ref[...].astype(jnp.float32)               # (bk, d)
+        v = v_ref[...].astype(jnp.float32)               # (bk, d)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * scale                                     # (bq, bk)
@@ -67,13 +65,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             mask &= (q_pos - k_pos) < window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[...]                               # (bq,)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_scr[...]                               # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = (acc_scr[...] * alpha[:, None]
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = (acc_scr[...] * alpha
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -81,8 +79,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(ik == num_kv_blocks - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_scr[...], 1e-30)
+        o_ref[...] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
@@ -103,29 +101,34 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
         _kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, num_kv_blocks=nk)
 
+    # heads go ahead of time and the batch and head dims are squeezed out
+    # of every block, so the kernel sees (block, d) tiles whose last two
+    # dims meet the (8, 128) tiling rule
+    qh, kh, vh = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     grid = (B, H, nq, nk)
+    sq = pl.squeezed
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d),
-                         lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((sq, sq, block_q, d),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((sq, sq, block_k, d),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((sq, sq, block_k, d),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, T, H, d), q.dtype),
+        out_specs=pl.BlockSpec((sq, sq, block_q, d),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, T, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),       # running max
-            pltpu.VMEM((block_q,), jnp.float32),       # running denominator
+            pltpu.VMEM((block_q, 1), jnp.float32),     # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),     # running denominator
             pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
-    return out
+    )(qh, kh, vh)
+    return out.transpose(0, 2, 1, 3)

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 
 def _kernel(loga_ref, b_ref, y_ref, hlast_ref, h_scr, *, nchunks, chunk):
     ic = pl.program_id(2)
@@ -30,12 +28,12 @@ def _kernel(loga_ref, b_ref, y_ref, hlast_ref, h_scr, *, nchunks, chunk):
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    a = jnp.exp(loga_ref[0].astype(jnp.float32))       # (Q, bw)
-    b = b_ref[0].astype(jnp.float32)                   # (Q, bw)
-
     def body(t, h):
-        h = a[t] * h + b[t]
-        y_ref[0, t, :] = h.astype(y_ref.dtype)
+        # one (1, bw) row per step, read and written through the refs:
+        # Mosaic lowers no dynamic index into a loaded value
+        a = jnp.exp(loga_ref[pl.ds(t, 1), :].astype(jnp.float32))
+        h = a * h + b_ref[pl.ds(t, 1), :].astype(jnp.float32)
+        y_ref[pl.ds(t, 1), :] = h.astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, chunk, body, h_scr[...])
@@ -43,7 +41,7 @@ def _kernel(loga_ref, b_ref, y_ref, hlast_ref, h_scr, *, nchunks, chunk):
 
     @pl.when(ic == nchunks - 1)
     def _final():
-        hlast_ref[0] = h.astype(hlast_ref.dtype)
+        hlast_ref[...] = h.astype(hlast_ref.dtype)
 
 
 def rglru_scan(log_a, b, *, chunk=256, block_w=None, interpret=None):
@@ -57,24 +55,25 @@ def rglru_scan(log_a, b, *, chunk=256, block_w=None, interpret=None):
         interpret = jax.default_backend() != "tpu"
 
     kernel = functools.partial(_kernel, nchunks=nc, chunk=Q)
+    sq = pl.squeezed
     y, hlast = pl.pallas_call(
         kernel,
         grid=(B, W // bw, nc),
         in_specs=[
-            pl.BlockSpec((1, Q, bw), lambda bb, w, c: (bb, c, w)),
-            pl.BlockSpec((1, Q, bw), lambda bb, w, c: (bb, c, w)),
+            pl.BlockSpec((sq, Q, bw), lambda bb, w, c: (bb, c, w)),
+            pl.BlockSpec((sq, Q, bw), lambda bb, w, c: (bb, c, w)),
         ],
         out_specs=[
-            pl.BlockSpec((1, Q, bw), lambda bb, w, c: (bb, c, w)),
-            pl.BlockSpec((1, bw), lambda bb, w, c: (bb, w)),
+            pl.BlockSpec((sq, Q, bw), lambda bb, w, c: (bb, c, w)),
+            pl.BlockSpec((sq, 1, bw), lambda bb, w, c: (bb, 0, w)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, T, W), jnp.float32),
-            jax.ShapeDtypeStruct((B, W), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, W), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bw,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, b)
-    return y, hlast
+    return y, hlast[:, 0, :]

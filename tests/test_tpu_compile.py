@@ -1,0 +1,157 @@
+"""Compile the main path's kernels and the jitted sweep for a TPU v5e chip
+that is described, not attached, at real widths.
+
+Interpret mode on the CPU cannot show what Mosaic refuses (a dynamic index
+into a loaded value, a block that breaks the (8, 128) tiling, an op with no
+lowering), so each kernel is compiled with ``interpret=False`` and must hold
+a ``tpu_custom_call``. Nothing runs: a pass says the chip's compiler accepts
+the program, not that it is fast or right (tests/test_kernels.py checks
+results in interpret mode).
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and every test worker imports this file.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from benchmarks.jaxsim_bench import candidate_placements
+from repro.core import jaxsim
+from repro.core import simulator as S
+from repro.core.simulator import _spec_graph
+from repro.kernels.cold_scan import cold_scan
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rglru_scan import rglru_scan
+from repro.kernels.rmsnorm import rmsnorm
+from repro.kernels.ssd_scan import ssd_scan
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip cannot be read back from the
+        # persistent cache without the chip; keep it out of the cache
+        cache_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def compile_for_chip(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
+def test_cold_scan_compiles(one_chip, dtype):
+    """One sweep row at the throughput shape (2^20 requests), in both of the
+    sweep's dtypes: the gaps are compared in the input dtype, the kernel
+    itself only selects bits."""
+    n = 2**20
+    with jax.enable_x64(dtype == jnp.float64):
+        compile_for_chip(
+            lambda t0, w, c: cold_scan(t0, w, c, 900.0, interpret=False),
+            spec(one_chip, (n,), dtype),
+            spec(one_chip, (1, n), dtype),
+            spec(one_chip, (1, n), dtype),
+        )
+
+
+def test_flash_attention_compiles_at_qwen3_widths(one_chip):
+    """qwen3-1.7b: 16 query heads, 8 kv heads, head_dim 128, T = 2048."""
+    q = spec(one_chip, (1, 2048, 16, 128), jnp.bfloat16)
+    kv = spec(one_chip, (1, 2048, 8, 128), jnp.bfloat16)
+    compile_for_chip(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False), q, kv, kv
+    )
+
+
+def test_rglru_scan_compiles_at_recurrentgemma_width(one_chip):
+    x = spec(one_chip, (1, 2048, 4096), jnp.float32)
+    compile_for_chip(
+        lambda a, b: rglru_scan(a, b, chunk=256, block_w=512, interpret=False), x, x
+    )
+
+
+def test_ssd_scan_compiles_at_mamba2_width(one_chip):
+    """mamba2-370m: 32 heads of 64, state 128, chunk 256."""
+    L, H, P, N = 2048, 32, 64, 128
+    compile_for_chip(
+        lambda x, dt, a, b, c: ssd_scan(x, dt, a, b, c, 256, interpret=False),
+        spec(one_chip, (1, L, H, P), jnp.float32),
+        spec(one_chip, (1, L, H), jnp.float32),
+        spec(one_chip, (H,), jnp.float32),
+        spec(one_chip, (1, L, N), jnp.float32),
+        spec(one_chip, (1, L, N), jnp.float32),
+    )
+
+
+def test_rmsnorm_compiles(one_chip):
+    compile_for_chip(
+        lambda x, w: rmsnorm(x, w, interpret=False),
+        spec(one_chip, (4096, 2048), jnp.bfloat16),
+        spec(one_chip, (2048,), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_sweep_compiles_with_the_pallas_cold_scan(one_chip, monkeypatch, dtype):
+    """The whole ``_sweep`` at the scorer's shape (8 seeds x 32 placements x
+    512 requests) with the kernel path forced and compiled: on the CPU the
+    kernel would otherwise pick interpret mode while tracing, and the
+    program would hold no kernel."""
+    monkeypatch.setattr(
+        jaxsim, "cold_scan_kernel", functools.partial(cold_scan, interpret=False)
+    )
+    n_seeds, n = 8, 512
+    placements = candidate_placements(32)
+    sim = S.WorkflowSimulator(S.paper_platforms(), seed=0)
+    order, _, preds, succs = _spec_graph(placements[0], None)
+    step_sets = [dict(enumerate(p)) for p in placements]
+    with jax.enable_x64(True):
+        placed, sigmas, graph, _ = jaxsim._build(
+            sim, order, step_sets, preds, succs, np.arange(n) * 1.0, None, dtype
+        )
+
+        def shaped(a):
+            a = np.asarray(a)
+            return spec(one_chip, a.shape, a.dtype)
+
+        args = (
+            spec(one_chip, (n_seeds, 2), np.uint32),
+            jax.tree_util.tree_map(shaped, placed),
+            jax.tree_util.tree_map(shaped, sigmas),
+            jax.tree_util.tree_map(shaped, graph),
+            spec(one_chip, (n,), dtype),
+            spec(one_chip, (), dtype),
+            spec(one_chip, (), dtype),
+        )
+        compiled = jaxsim._sweep.lower(
+            *args, None, prefetch=True, use_drift=False, use_pallas=True,
+            use_stream=False, use_faults=False,
+        ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
