@@ -1,0 +1,9 @@
+"""Device time per prefill (``models/`` prefill programs, one per prompt
+length): the mean duration of their executions in the traced window, in
+ms."""
+
+from bench.xtrace import module_mean_ms
+
+
+def read(ctx):
+    return module_mean_ms(ctx.devices[0], "jit_serve_prefill_")
