@@ -80,7 +80,7 @@ def _jax_backend() -> str:
 def _write_bench_json(name: str, wall_s: float, rows, quick: bool = False) -> None:
     """One JSON artifact per bench: rows (when the bench returned a dict)
     + wall time, stamped with the commit SHA, UTC timestamp and run flags
-    so ``scripts/bench_trend.py`` can line artifacts up across commits.
+    so artifacts from different commits can be told apart.
     Non-serializable values degrade to strings rather than failing the
     bench."""
     os.makedirs(BENCH_OUT, exist_ok=True)

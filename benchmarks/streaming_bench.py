@@ -12,8 +12,7 @@ Two parts, mirroring dag_overlap:
     the same payload to show the direct path under the threshold.
 
 Output: CSV-ish ``name,median_s`` rows (written to
-``experiments/bench/BENCH_streaming.json`` by the runner, trended by
-``scripts/bench_trend.py``).
+``experiments/bench/BENCH_streaming.json`` by the runner).
 """
 
 from __future__ import annotations

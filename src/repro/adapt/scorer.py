@@ -32,6 +32,7 @@ from repro.core.simulator import (
     SimStep,
     WorkflowSimulator,
 )
+from repro.obs.trace import span
 
 
 class _CostSimulator(WorkflowSimulator):
@@ -121,39 +122,51 @@ class PlacementScorer:
         the DAG edge list. On ``backend="jax"`` all rows come from ONE
         jitted sweep; on ``"numpy"``/``"scalar"`` each row is its own
         experiment on the same seeds (bit-identical draws either way
-        within a backend — the CRN guarantee)."""
-        order = list(nodes)
-        platforms = self._platforms(placements)
-        step_sets = [self._steps(nodes, order, p, costs) for p in placements]
-        sim = _CostSimulator(
-            costs,
-            platforms,
-            msg_latency_s=self.msg_latency_s,
-            payload_size_bytes=costs.payload_size,
-            seed=self.seed,
-        )
-        spec = ExperimentSpec(
-            step_sets[0],
-            edges=tuple(edges),
-            n_requests=self.n_requests,
-            interarrival_s=self.interarrival_s,
-            prefetch=prefetch,
-            seeds=self.seeds if self.seeds is not None else (self.seed,),
-        )
-        if self.backend == "jax":
-            totals = sim.simulate_placements(spec, step_sets, dtype=np.float32)
-        else:
-            totals = np.stack(
-                [
-                    sim.simulate(replace(spec, steps=ss), backend=self.backend)
-                    for ss in step_sets
-                ],
-                axis=1,
-            )
-        # (S, P, n) -> (P, S * n): rows are placements, columns samples
-        return np.ascontiguousarray(np.swapaxes(totals, 0, 1)).reshape(
-            len(placements), -1
-        )
+        within a backend — the CRN guarantee). Under a profiler session the
+        call records the ``geoff.scorer`` span with its ``world`` and
+        ``collect`` phases (``repro.obs.span``)."""
+        seeds = self.seeds if self.seeds is not None else (self.seed,)
+        with span(
+            "geoff.scorer",
+            placements=len(placements),
+            seeds=len(seeds),
+            requests=self.n_requests,
+            backend=self.backend,
+        ):
+            with span("geoff.scorer.world"):
+                order = list(nodes)
+                platforms = self._platforms(placements)
+                step_sets = [self._steps(nodes, order, p, costs) for p in placements]
+                sim = _CostSimulator(
+                    costs,
+                    platforms,
+                    msg_latency_s=self.msg_latency_s,
+                    payload_size_bytes=costs.payload_size,
+                    seed=self.seed,
+                )
+                spec = ExperimentSpec(
+                    step_sets[0],
+                    edges=tuple(edges),
+                    n_requests=self.n_requests,
+                    interarrival_s=self.interarrival_s,
+                    prefetch=prefetch,
+                    seeds=seeds,
+                )
+            if self.backend == "jax":
+                totals = sim.simulate_placements(spec, step_sets, dtype=np.float32)
+            else:
+                totals = np.stack(
+                    [
+                        sim.simulate(replace(spec, steps=ss), backend=self.backend)
+                        for ss in step_sets
+                    ],
+                    axis=1,
+                )
+            with span("geoff.scorer.collect"):
+                # (S, P, n) -> (P, S * n): rows are placements, columns samples
+                return np.ascontiguousarray(np.swapaxes(totals, 0, 1)).reshape(
+                    len(placements), -1
+                )
 
     def quantiles(
         self, nodes, edges, placements, costs: PlacementCosts, prefetch: bool = True

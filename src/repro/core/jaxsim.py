@@ -62,6 +62,7 @@ import numpy as np
 
 from repro.kernels.cold_scan import cold_scan_parallel
 from repro.kernels.ops import cold_scan as cold_scan_kernel
+from repro.obs.trace import span
 
 
 class _Graph(NamedTuple):
@@ -466,7 +467,7 @@ def _build(
 
 def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
                 drift=None, dtype=np.float64, sample_idx=None, stream=None,
-                faults=None, retry=None):
+                faults=None, retry=None, build=None):
     """The jax backend's one entry point: simulate every (seed, placement)
     pair of one workflow graph in a single compiled call.
 
@@ -507,6 +508,13 @@ def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
     are identical across every placement's SHARED (step, platform) cells
     (a moved step gets the moved cell's plane — what lets the scorer judge
     failover candidates under live outages).
+
+    ``build``: the caller's open ``geoff.sweep.build`` program span
+    (``repro.obs.span``; ``simulate_placements`` opens it at its entry),
+    which ends here where the sweep is dispatched, with the ``host_bytes``
+    handed to the sweep as its counter. The phases after it are the
+    ``geoff.sweep.dispatch``, ``.wait`` and ``.fetch`` spans, the last
+    with the ``fetched_bytes`` read back.
     """
     if drift is None:
         drift = sim.drift
@@ -549,7 +557,7 @@ def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
         keys = np.stack(
             [sarr >> np.uint64(32), sarr & np.uint64(0xFFFFFFFF)], axis=-1
         ).astype(np.uint32)
-        out = _sweep(
+        args = (
             keys,
             placed,
             sigmas,
@@ -560,12 +568,26 @@ def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
             jnp.asarray(np.asarray(sample_idx, np.int32))
             if sample_idx is not None
             else None,
-            prefetch=bool(prefetch),
-            use_drift=drift is not None,
-            use_pallas=jax.default_backend() == "tpu",
-            use_stream=use_stream,
-            use_faults=use_faults,
         )
+        if build is not None:
+            if build.record is not None:
+                build.set(host_bytes=sum(a.nbytes for a in jax.tree.leaves(args)))
+            build.end()
+        with span("geoff.sweep.dispatch"):
+            out = _sweep(
+                *args,
+                prefetch=bool(prefetch),
+                use_drift=drift is not None,
+                use_pallas=jax.default_backend() == "tpu",
+                use_stream=use_stream,
+                use_faults=use_faults,
+            )
+        with span("geoff.sweep.wait"):
+            # queue the copies to the host behind the sweep, where reading
+            # the pending result would queue them, before waiting for it
+            for a in jax.tree.leaves(out):
+                a.copy_to_host_async()
+            jax.block_until_ready(out)
 
         def mark_failed(totals):
             # dead requests are priced as-if-completed inside the sweep
@@ -576,10 +598,10 @@ def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
                 return np.where(fault_failed[None, :, :], np.inf, totals)
             return totals
 
+        with span("geoff.sweep.fetch") as fetch:
+            fetched = [np.asarray(a) for a in jax.tree.leaves(out)]
+            fetch.set(fetched_bytes=sum(a.nbytes for a in fetched))
+            totals = mark_failed(fetched[0])
         if sample_idx is not None:
-            totals, sampled = out
-            return (
-                mark_failed(np.asarray(totals)),
-                tuple(np.asarray(a) for a in sampled),
-            )
-        return mark_failed(np.asarray(out))
+            return totals, tuple(fetched[1:])
+        return totals

@@ -1261,63 +1261,76 @@ class WorkflowSimulator:
         tracer=...)``: sampled per-request ``obs`` traces are rebuilt
         host-side for the FIRST seed and FIRST placement (the spec's own
         steps when called through ``simulate``). Public placement-scoring
-        callers never pass it, so the scorer path stays pure."""
-        from repro.core import jaxsim  # deferred: jax pays init cost
+        callers never pass it, so the scorer path stays pure.
 
-        telemetry = spec.telemetry if spec.telemetry is not None else self.telemetry
-        if telemetry is not None:
-            raise ValueError(
-                "backend='jax' does not support telemetry=: observations "
-                "are per-request side effects; use backend='numpy'"
-            )
-        placements = [tuple(p) for p in placements]
-        if not placements:
-            raise ValueError("placements must be non-empty")
-        order, _, preds, succs = _spec_graph(placements[0], spec.edges)
-        if spec.edges is None:
-            step_sets = [dict(enumerate(p)) for p in placements]
-        else:
-            step_sets = [{s.name: s for s in p} for p in placements]
-        seeds = spec.seeds if spec.seeds is not None else (self.seed,)
-        drift = spec.drift if spec.drift is not None else self.drift
-        stream = spec.stream if spec.stream is not None else self.stream
-        faults = spec.faults if spec.faults is not None else self.faults
-        retry = spec.retry if spec.retry is not None else self.retry
-        t0s = np.arange(spec.n_requests) * spec.interarrival_s
-        if _tracer is None:
-            return jaxsim.run_batched(
-                self, order, step_sets, preds, succs, t0s, spec.prefetch,
-                list(seeds), drift=drift, dtype=dtype, stream=stream,
-                faults=faults, retry=retry,
-            )
-        sample_idx = np.unique(
-            np.linspace(
-                0,
-                max(spec.n_requests - 1, 0),
-                min(getattr(_tracer, "sample", 8) or 0, spec.n_requests),
-            )
-            .round()
-            .astype(int)
-        )
-        totals, sampled = jaxsim.run_batched(
-            self, order, step_sets, preds, succs, t0s, spec.prefetch,
-            list(seeds), drift=drift, dtype=dtype, sample_idx=sample_idx,
-            stream=stream, faults=faults, retry=retry,
-        )
-        self._emit_traces_jax(
-            order,
-            step_sets[0],
-            preds,
-            spec.prefetch,
-            t0s,
-            sample_idx,
-            tuple(a[0, 0] for a in sampled),  # first seed, first placement
-            drift,
-            _tracer,
-            seed=seeds[0],
-            stream=stream,
-        )
-        return totals
+        Under a profiler session the call records the ``geoff.sweep`` span
+        (counters ``rows``, ``requests``), tiled by its ``build``,
+        ``dispatch``, ``wait`` and ``fetch`` phases and, with ``_tracer``,
+        ``traces`` (``repro.obs.span``)."""
+        from repro.core import jaxsim  # deferred: jax pays init cost
+        from repro.obs.trace import span
+
+        with span("geoff.sweep", requests=spec.n_requests) as sweep:
+            # the build ends inside run_batched, at the dispatch; the with
+            # closes it only on an error before then
+            with span("geoff.sweep.build") as build:
+                telemetry = (
+                    spec.telemetry if spec.telemetry is not None else self.telemetry
+                )
+                if telemetry is not None:
+                    raise ValueError(
+                        "backend='jax' does not support telemetry=: observations "
+                        "are per-request side effects; use backend='numpy'"
+                    )
+                placements = [tuple(p) for p in placements]
+                if not placements:
+                    raise ValueError("placements must be non-empty")
+                order, _, preds, succs = _spec_graph(placements[0], spec.edges)
+                if spec.edges is None:
+                    step_sets = [dict(enumerate(p)) for p in placements]
+                else:
+                    step_sets = [{s.name: s for s in p} for p in placements]
+                seeds = spec.seeds if spec.seeds is not None else (self.seed,)
+                sweep.set(rows=len(seeds) * len(placements))
+                drift = spec.drift if spec.drift is not None else self.drift
+                stream = spec.stream if spec.stream is not None else self.stream
+                faults = spec.faults if spec.faults is not None else self.faults
+                retry = spec.retry if spec.retry is not None else self.retry
+                t0s = np.arange(spec.n_requests) * spec.interarrival_s
+                sample_idx = None
+                if _tracer is not None:
+                    sample_idx = np.unique(
+                        np.linspace(
+                            0,
+                            max(spec.n_requests - 1, 0),
+                            min(getattr(_tracer, "sample", 8) or 0, spec.n_requests),
+                        )
+                        .round()
+                        .astype(int)
+                    )
+                out = jaxsim.run_batched(
+                    self, order, step_sets, preds, succs, t0s, spec.prefetch,
+                    list(seeds), drift=drift, dtype=dtype, sample_idx=sample_idx,
+                    stream=stream, faults=faults, retry=retry, build=build,
+                )
+            if _tracer is None:
+                return out
+            totals, sampled = out
+            with span("geoff.sweep.traces"):
+                self._emit_traces_jax(
+                    order,
+                    step_sets[0],
+                    preds,
+                    spec.prefetch,
+                    t0s,
+                    sample_idx,
+                    tuple(a[0, 0] for a in sampled),  # first seed, first placement
+                    drift,
+                    _tracer,
+                    seed=seeds[0],
+                    stream=stream,
+                )
+            return totals
 
     def _emit_traces_jax(
         self, order, steps, preds, prefetch, t0s, sample_idx, sampled,

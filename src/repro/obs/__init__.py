@@ -18,6 +18,10 @@ closes the loop on (``trigger="slo"``).
 
 ``instrument(deployment)`` wires a tracer into a live deployment the same
 way ``repro.adapt.attach`` wires telemetry.
+
+``span`` times the control plane's own phases (``geoff.scorer.*``,
+``geoff.sweep.*``) while a ``jax.profiler`` session records: into the
+profiler's trace and into a ring that ``program_spans`` reads.
 """
 
 from repro.obs.critical_path import (
@@ -37,7 +41,15 @@ from repro.obs.profiler import (
 )
 from repro.obs.sampler import TailSampler
 from repro.obs.slo import SloSpec, SloTracker
-from repro.obs.trace import Span, Trace, Tracer, instrument
+from repro.obs.trace import (
+    Span,
+    Trace,
+    Tracer,
+    clear_program_spans,
+    instrument,
+    program_spans,
+    span,
+)
 
 __all__ = [
     "BUCKETS",
@@ -56,9 +68,12 @@ __all__ = [
     "WhatIfProfiler",
     "WindowedHistogram",
     "calibrate",
+    "clear_program_spans",
     "extract_critical_path",
     "instrument",
     "profile_trace",
+    "program_spans",
+    "span",
     "to_chrome_trace",
     "write_chrome_trace",
 ]

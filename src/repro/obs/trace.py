@@ -44,11 +44,21 @@ only to append, finished traces live in a bounded ring, and every producer
 guards with ``if tracer is not None`` so the untraced path is untouched —
 the same zero-overhead-when-off discipline as the telemetry hooks, with
 the same draw-neutrality guarantee in the simulator (pinned by test).
+
+Program spans (``span``) time the control plane's own phases — the
+scorer's world build, the sweep's host build, dispatch, wait and fetch —
+on the profiler's clock. They record exactly while a ``jax.profiler``
+session does: each opens a ``jax.profiler.TraceAnnotation`` of its name,
+so it lands on a ``/host:`` plane of the profiler's trace beside the
+device's ops, and its finished ``Span`` (``kind="program"``, perf_counter
+seconds) goes into a bounded process-wide ring that ``program_spans``
+reads. With no session a span costs one ``is_enabled`` check.
 """
 
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 from collections import deque
@@ -293,3 +303,88 @@ def instrument(deployment, tracer: Optional[Tracer] = None) -> Tracer:
     deployment.prefetcher.tracer = tracer
     deployment.store.tracer = tracer
     return tracer
+
+
+# -- program spans: the control plane's phases, on the profiler's clock --------
+# room for a whole traced window: some 600 decisions of 8 spans each
+_program_ring: deque = deque(maxlen=16384)
+_program_tls = threading.local()
+_TraceAnnotation = None  # jax.profiler's, once jax is imported
+
+
+def _profiling() -> bool:
+    """Whether a profiler session records. Without jax imported no session
+    can; ``repro.obs`` never imports jax itself."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return False
+        _TraceAnnotation = jax.profiler.TraceAnnotation
+    return _TraceAnnotation.is_enabled()
+
+
+class span:
+    """A program span: ``with span("geoff.sweep", rows=64) as s:``.
+
+    Opens at construction while a profiler session records, and does
+    nothing otherwise (``record`` stays None). Its parent is the calling
+    thread's open program span, whose ``trace_id`` it shares, so the spans
+    of one decision or one sweep carry one identifier. ``set`` adds
+    counters to the open span; ``end`` closes it before the ``with``
+    block does, for a phase that ends inside a callee (the sweep's build
+    ends where ``run_batched`` dispatches). Spans end in the reverse order
+    they opened, on the thread that opened them."""
+
+    __slots__ = ("record", "_annotation", "_parent")
+
+    def __init__(self, name: str, **attrs):
+        self.record = None
+        if not _profiling():
+            return
+        parent = getattr(_program_tls, "span", None)
+        sid = next(_ids)
+        self._parent = parent
+        self._annotation = _TraceAnnotation(name)
+        self._annotation.__enter__()
+        self.record = Span(
+            sid,
+            parent.trace_id if parent is not None else f"p{sid:08x}",
+            parent.span_id if parent is not None else None,
+            name,
+            "program",
+            time.perf_counter(),
+            attrs,
+        )
+        _program_tls.span = self.record
+
+    def __enter__(self) -> "span":
+        return self
+
+    def __exit__(self, *exc):
+        self.end()
+        return False
+
+    def set(self, **counters):
+        if self.record is not None:
+            self.record.attrs.update(counters)
+
+    def end(self):
+        s = self.record
+        if s is None or s.t_end is not None:
+            return
+        s.end()
+        self._annotation.__exit__(None, None, None)
+        _program_tls.span = self._parent
+        _program_ring.append(s)
+
+
+def program_spans(name: Optional[str] = None) -> list:
+    """The finished program spans the ring holds, oldest first; only those
+    named ``name`` when given."""
+    spans = list(_program_ring)
+    return spans if name is None else [s for s in spans if s.name == name]
+
+
+def clear_program_spans():
+    _program_ring.clear()
