@@ -109,7 +109,13 @@ class _Placement(NamedTuple):
     #   device rng, so it rides the scan like the drift masks do)
 
 
+def use_pallas() -> bool:
+    """Whether the sweep runs the Pallas cold-scan kernel: on a TPU."""
+    return jax.default_backend() == "tpu"
+
+
 def _cold_mask(t0s, warm_end, cold_end, keep_warm, use_pallas):
+    # under _sweep's two vmaps each (1, n) row joins one kernel call's lanes
     if use_pallas:
         return cold_scan_kernel(t0s, warm_end[None, :], cold_end[None, :], keep_warm)[0]
     return cold_scan_parallel(t0s, warm_end, cold_end, keep_warm)
@@ -578,7 +584,7 @@ def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
                 *args,
                 prefetch=bool(prefetch),
                 use_drift=drift is not None,
-                use_pallas=jax.default_backend() == "tpu",
+                use_pallas=use_pallas(),
                 use_stream=use_stream,
                 use_faults=use_faults,
             )

@@ -1264,10 +1264,13 @@ class WorkflowSimulator:
         callers never pass it, so the scorer path stays pure.
 
         Under a profiler session the call records the ``geoff.sweep`` span
-        (counters ``rows``, ``requests``), tiled by its ``build``,
+        (counters ``rows``, ``requests`` and, where the sweep runs the
+        Pallas cold scan, ``kernel_lanes``: the lane width of each node's
+        one kernel call), tiled by its ``build``,
         ``dispatch``, ``wait`` and ``fetch`` phases and, with ``_tracer``,
         ``traces`` (``repro.obs.span``)."""
         from repro.core import jaxsim  # deferred: jax pays init cost
+        from repro.kernels.cold_scan import kernel_lanes
         from repro.obs.trace import span
 
         with span("geoff.sweep", requests=spec.n_requests) as sweep:
@@ -1291,7 +1294,10 @@ class WorkflowSimulator:
                 else:
                     step_sets = [{s.name: s for s in p} for p in placements]
                 seeds = spec.seeds if spec.seeds is not None else (self.seed,)
-                sweep.set(rows=len(seeds) * len(placements))
+                rows = len(seeds) * len(placements)
+                sweep.set(rows=rows)
+                if jaxsim.use_pallas():
+                    sweep.set(kernel_lanes=kernel_lanes(rows))
                 drift = spec.drift if spec.drift is not None else self.drift
                 stream = spec.stream if spec.stream is not None else self.stream
                 faults = spec.faults if spec.faults is not None else self.faults

@@ -24,8 +24,13 @@ Two device implementations of the same recurrence:
                         compared before the kernel, in the inputs' dtype,
                         so the kernel selects bits and never rounds a time.
                         Time is the sublane dimension so the per-step store
-                        is a full lane row. On non-TPU backends it runs in
-                        interpret mode.
+                        is a full lane row. Rows are lanes: under ``vmap``
+                        (the simulator's seeds x placements, one row each)
+                        a batching rule folds the batch into the row axis,
+                        so a whole sweep is one call with its rows side by
+                        side in 128-lane blocks, not a grid of one-row
+                        tiles. On non-TPU backends it runs in interpret
+                        mode.
 
 ``cold_scan_parallel``  the same mask with the sequential dependence
                         factored out, for XLA on any backend: mask[k] is a
@@ -96,25 +101,21 @@ def _gap_bits(t0, warm_end, cold_end, keep_warm):
     )
 
 
-def cold_scan(
-    t0, warm_end, cold_end, keep_warm, *, chunk=256, block_b=128, interpret=None
-):
-    """Boolean cold mask, request-major. ``t0``: (T,) arrival times shared
-    by every row; ``warm_end``/``cold_end``: (B, T) per-row end times under
-    the warm / cold hypothesis; ``keep_warm``: scalar idle horizon (may be
-    +inf: never cold). Returns (B, T) bool. The gaps are compared in the
-    inputs' dtype outside the kernel (``_gap_bits``), packed as the code
-    ``warm_bit | cold_bit << 1``; the kernel only selects bits, so the
-    mask is exact for f32 and f64 inputs alike."""
-    B, T = warm_end.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    warm_bit, cold_bit = _gap_bits(t0, warm_end, cold_end, keep_warm)
-    code = warm_bit.astype(jnp.int32) | (cold_bit.astype(jnp.int32) << 1)
+def kernel_lanes(rows, block_b=128):
+    """Lane width of the kernel call over ``rows`` rows: whole blocks of
+    ``block_b``."""
+    return -(-rows // block_b) * block_b
+
+
+def _scan_rows(code, *, chunk, block_b, interpret):
+    """The kernel over a (B, T) int32 code plane: rows padded to lane
+    blocks of ``block_b``, time to chunks of ``chunk``. Returns (B, T)
+    bool."""
+    B, T = code.shape
     # pad to tile multiples; the scan runs forward so padded time steps
     # never influence real outputs, and padded rows are sliced away
     Tp = -(-T // chunk) * chunk
-    Bp = -(-B // block_b) * block_b
+    Bp = kernel_lanes(B, block_b)
     codep = jnp.zeros((Tp, Bp), jnp.int32).at[:T, :B].set(code.T)
 
     kernel = functools.partial(_kernel, chunk=chunk)
@@ -130,6 +131,46 @@ def cold_scan(
         interpret=interpret,
     )(codep)
     return mask[:T, :B].T > 0
+
+
+@functools.cache
+def _folded_scan(chunk, block_b, interpret):
+    """``_scan_rows`` with a batching rule that folds each vmapped axis into
+    the row (lane) axis: under ``vmap`` an (axis_size, B, T) plane becomes
+    one (axis_size * B, T) call instead of a grid of one tile per batch
+    member. Rows are independent (the recurrence runs along time only), so
+    the mask is the same; nested vmaps fold again, one axis per level."""
+
+    @jax.custom_batching.custom_vmap
+    def scan(code):
+        return _scan_rows(code, chunk=chunk, block_b=block_b, interpret=interpret)
+
+    @scan.def_vmap
+    def _fold(axis_size, in_batched, code):
+        del in_batched  # the one input: a rule only runs on a batched input
+        _, B, T = code.shape
+        return scan(code.reshape(axis_size * B, T)).reshape(axis_size, B, T), True
+
+    return scan
+
+
+def cold_scan(
+    t0, warm_end, cold_end, keep_warm, *, chunk=256, block_b=128, interpret=None
+):
+    """Boolean cold mask, request-major. ``t0``: (T,) arrival times shared
+    by every row; ``warm_end``/``cold_end``: (B, T) per-row end times under
+    the warm / cold hypothesis; ``keep_warm``: scalar idle horizon (may be
+    +inf: never cold). Returns (B, T) bool. The gaps are compared in the
+    inputs' dtype outside the kernel (``_gap_bits``), packed as the code
+    ``warm_bit | cold_bit << 1``; the kernel only selects bits, so the
+    mask is exact for f32 and f64 inputs alike. Under ``vmap`` (per-row
+    ``keep_warm`` included) every batch member's rows join the one kernel
+    call's lanes (``_folded_scan``)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    warm_bit, cold_bit = _gap_bits(t0, warm_end, cold_end, keep_warm)
+    code = warm_bit.astype(jnp.int32) | (cold_bit.astype(jnp.int32) << 1)
+    return _folded_scan(chunk, block_b, interpret)(code)
 
 
 def cold_scan_parallel(t0, warm_end, cold_end, keep_warm):

@@ -109,6 +109,35 @@ def test_sigma0_cold_regime_matches_numpy_exactly():
     np.testing.assert_allclose(b, a, atol=ATOL, rtol=0)
 
 
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_pallas_sweep_matches_parallel_scan_in_a_cold_regime(monkeypatch, dtype):
+    """The whole sweep with the Pallas cold scan (interpret mode here), its
+    (seed, placement) rows folded into the kernel's lanes, against the
+    parallel-scan sweep: 2 seeds x 4 placements x 600 requests with the
+    arrival gap (3.0 s) straddling keep_warm (2.5 s), so the mask decides
+    many totals. A row mixed up in the fold moves a total by its cold
+    start; the totals must be equal bit for bit."""
+    from benchmarks.jaxsim_bench import candidate_placements
+    from repro.core import jaxsim
+
+    placements = candidate_placements(4)
+    spec = S.ExperimentSpec(
+        placements[0], n_requests=600, interarrival_s=3.0, seeds=(3, 4)
+    )
+    plats = [replace(p, keep_warm_s=2.5) for p in S.paper_platforms()]
+
+    def sweep(platforms, pallas):
+        monkeypatch.setattr(jaxsim, "use_pallas", lambda: pallas)
+        sim = S.WorkflowSimulator(platforms, seed=0)
+        return sim.simulate_placements(spec, placements, dtype=dtype)
+
+    kernel, parallel = sweep(plats, True), sweep(plats, False)
+    assert kernel.shape == (2, 4, 600) and kernel.dtype == dtype
+    assert kernel.tobytes() == parallel.tobytes()
+    warm = sweep([replace(p, keep_warm_s=math.inf) for p in plats], False)
+    assert 0.2 < np.mean(kernel != warm) < 1.0  # cold starts in most rows
+
+
 def test_sigma0_drift_matches_numpy_exactly():
     drift = S.DriftSchedule(
         [

@@ -11,7 +11,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
-from repro.kernels.cold_scan import cold_scan_parallel
+from repro.kernels.cold_scan import _gap_bits, cold_scan_parallel
 
 KEY = jax.random.PRNGKey(0)
 
@@ -192,3 +192,35 @@ def test_cold_scan_parallel_under_vmap():
     for i in range(4):
         want = ref.cold_scan_ref(t0[i], warm[i], cold[i], 0.95)
         np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want))
+
+
+def test_cold_scan_kernel_under_nested_vmap_folds_rows_into_lanes():
+    """As the simulator's sweep calls the kernel: one (1, T) row per (seed,
+    placement) under vmap(vmap(...)), arrivals shared, each row with its
+    own keep_warm, in the straddling regime. The 3 x 50 rows fold into one
+    kernel call over two 128-lane blocks, and every row's mask is the
+    reference's bit for bit."""
+    n_s, n_p, T = 3, 50, 300
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(9), 4)
+    t0 = jnp.cumsum(0.5 + jax.random.uniform(k1, (T,)))
+    warm = t0 + 0.3 * jax.random.uniform(k2, (n_s, n_p, T))
+    cold = warm + 0.3 * jax.random.uniform(k3, (n_s, n_p, T))
+    kw = 0.85 + 0.2 * jax.random.uniform(k4, (n_s, n_p))
+
+    def row(w, c, k):
+        return ops.cold_scan(t0, w[None, :], c[None, :], k)[0]
+
+    sweep = jax.vmap(jax.vmap(row))
+    program = str(jax.make_jaxpr(sweep)(warm, cold, kw))
+    assert program.count("pallas_call") == 1
+    assert "i32[512,256] = pallas_call" in program
+    got = np.asarray(sweep(warm, cold, kw))
+    assert got.shape == (n_s, n_p, T)
+    # the reference's recurrence runs on every row at once, each row
+    # against its own keep_warm (broadcast over the rows' carry)
+    want = np.asarray(ref.cold_scan_ref(t0, warm, cold, kw))
+    np.testing.assert_array_equal(got, want)
+    warm_bit, cold_bit = _gap_bits(t0, warm, cold, kw[..., None])
+    flips = np.asarray(warm_bit & ~cold_bit).sum(axis=-1)
+    assert 0.05 < got[:, :, 1:].mean() < 0.95  # both states occur
+    assert (flips > 0).all()  # every row has requests whose state follows

@@ -190,3 +190,25 @@ def test_spans_nest_per_thread_and_end_once(tmp_path):
     assert b.record.parent_id == a.record.span_id
     assert c.record.parent_id == a.record.span_id  # b ended: a is current again
     assert getattr(obs_trace._program_tls, "span", None) is None
+
+
+@pytest.mark.parametrize("n_seeds,n_placements,lanes", [(1, 1, 128), (10, 13, 256)])
+def test_sweep_span_counts_the_kernel_lanes_on_the_pallas_path(
+    tmp_path, monkeypatch, n_seeds, n_placements, lanes
+):
+    """With the Pallas cold scan (interpret mode here) every (seed,
+    placement) row joins one kernel call per node, whose lane width the
+    ``geoff.sweep`` span counts: whole 128-lane blocks."""
+    monkeypatch.setattr(jaxsim, "use_pallas", lambda: True)
+    sim = S.WorkflowSimulator(S.paper_platforms(), seed=0)
+    fig4 = S.document_workflow_fig4()
+    spec = S.ExperimentSpec(fig4, n_requests=16, seeds=tuple(range(n_seeds)))
+    clear_program_spans()
+    with jax.profiler.trace(str(tmp_path)):
+        out = sim.simulate_placements(spec, [fig4] * n_placements)
+    (sweep,) = program_spans("geoff.sweep")
+    clear_program_spans()
+    assert out.shape == (n_seeds, n_placements, 16)
+    assert sweep.attrs == {
+        "requests": 16, "rows": n_seeds * n_placements, "kernel_lanes": lanes,
+    }

@@ -14,6 +14,7 @@ process may load the TPU library, and every test worker imports this file.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,11 +22,12 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from bench import xtrace
 from benchmarks.jaxsim_bench import candidate_placements
 from repro.core import jaxsim
 from repro.core import simulator as S
 from repro.core.simulator import _spec_graph
-from repro.kernels.cold_scan import cold_scan
+from repro.kernels.cold_scan import cold_scan, kernel_lanes
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.rmsnorm import rmsnorm
@@ -118,17 +120,17 @@ def test_rmsnorm_compiles(one_chip):
     )
 
 
-@pytest.mark.parametrize("dtype", [np.float32, np.float64])
-def test_sweep_compiles_with_the_pallas_cold_scan(one_chip, monkeypatch, dtype):
-    """The whole ``_sweep`` at the scorer's shape (8 seeds x 32 placements x
-    512 requests) with the kernel path forced and compiled: on the CPU the
-    kernel would otherwise pick interpret mode while tracing, and the
-    program would hold no kernel."""
+def compile_sweep(sharding, monkeypatch, n_seeds, n_placements, n, dtype):
+    """The whole ``_sweep`` with the kernel path forced and compiled: on the
+    CPU the kernel would otherwise pick interpret mode while tracing, and
+    the program would hold no kernel. Forced through a jit named
+    ``cold_scan``, as ``ops.cold_scan`` is: the kernel's instruction takes
+    the name, and the device trace's reader finds the kernel by it."""
+    forced = functools.partial(cold_scan, interpret=False)
     monkeypatch.setattr(
-        jaxsim, "cold_scan_kernel", functools.partial(cold_scan, interpret=False)
+        jaxsim, "cold_scan_kernel", jax.jit(functools.wraps(cold_scan)(forced))
     )
-    n_seeds, n = 8, 512
-    placements = candidate_placements(32)
+    placements = candidate_placements(n_placements)
     sim = S.WorkflowSimulator(S.paper_platforms(), seed=0)
     order, _, preds, succs = _spec_graph(placements[0], None)
     step_sets = [dict(enumerate(p)) for p in placements]
@@ -139,19 +141,54 @@ def test_sweep_compiles_with_the_pallas_cold_scan(one_chip, monkeypatch, dtype):
 
         def shaped(a):
             a = np.asarray(a)
-            return spec(one_chip, a.shape, a.dtype)
+            return spec(sharding, a.shape, a.dtype)
 
         args = (
-            spec(one_chip, (n_seeds, 2), np.uint32),
+            spec(sharding, (n_seeds, 2), np.uint32),
             jax.tree_util.tree_map(shaped, placed),
             jax.tree_util.tree_map(shaped, sigmas),
             jax.tree_util.tree_map(shaped, graph),
-            spec(one_chip, (n,), dtype),
-            spec(one_chip, (), dtype),
-            spec(one_chip, (), dtype),
+            spec(sharding, (n,), dtype),
+            spec(sharding, (), dtype),
+            spec(sharding, (), dtype),
         )
-        compiled = jaxsim._sweep.lower(
+        return jaxsim._sweep.lower(
             *args, None, prefetch=True, use_drift=False, use_pallas=True,
             use_stream=False, use_faults=False,
         ).compile()
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_sweep_compiles_with_the_pallas_cold_scan(one_chip, monkeypatch, dtype):
+    """The whole ``_sweep`` at the scorer's shape (8 seeds x 32 placements x
+    512 requests), in both of the sweep's dtypes."""
+    compiled = compile_sweep(one_chip, monkeypatch, 8, 32, 512, dtype)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize(
+    "n_seeds,n_placements,n", [(8, 32, 512), (2, 4, 2**20)], ids=["decide", "throughput"]
+)
+def test_sweep_folds_its_rows_into_one_cold_scan_call(
+    one_chip, monkeypatch, n_seeds, n_placements, n
+):
+    """At the benchmark's two sweep shapes the (seed, placement) rows fold
+    into the kernel's lanes: the program holds one kernel, named
+    ``cold_scan`` as the device trace's reader finds it, over one 2-D
+    (requests, lanes) plane; not a tile of 128 lanes per row, which took
+    8.9 GiB of temporaries at the throughput shape."""
+    compiled = compile_sweep(one_chip, monkeypatch, n_seeds, n_placements, n,
+                             np.float32)
+    kernels = [
+        line for line in compiled.as_text().splitlines() if xtrace.is_kernel(line)
+    ]
+    assert len(kernels) == 1
+    assert xtrace.base_name(xtrace.instr_name(kernels[0])) == "cold_scan"
+    (operand,) = re.findall(r"operand_layout_constraints=\{s32\[([0-9,]*)\]",
+                            kernels[0])
+    rows = n_seeds * n_placements
+    lanes = 128 * -(-rows // 128)
+    assert kernel_lanes(rows) == lanes
+    assert tuple(int(d) for d in operand.split(",")) == (-(-n // 256) * 256, lanes)
+    if n == 2**20:
+        assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
