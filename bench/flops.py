@@ -57,7 +57,10 @@ def flash_attention_work(q_shape, k_shape, causal: bool = True, itemsize: int = 
 
 
 def cold_scan_work(rows: int, requests: int, itemsize: int = 4):
-    """(flops, bytes) of one cold-scan call over ``rows`` x ``requests``:
-    the code plane in and the mask out, unpadded. A select per element is
-    no matrix work, so the call is counted as memory traffic alone."""
+    """(flops, bytes) of the cold scans over ``rows`` x ``requests``
+    elements: the code plane in and the mask out, unpadded. A sweep's scans
+    cover nodes x (seed, placement) rows; a program may split them over
+    several kernel calls, so a reader spreads a sweep's work over the calls
+    one sweep makes. A select per element is no matrix work, so the scan is
+    counted as memory traffic alone."""
     return 0.0, 2 * itemsize * rows * requests

@@ -325,11 +325,13 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         devs = xtrace.device_traces(planes, lo, hi)[: cell.chips]
         if not devs:
             raise RuntimeError("the trace holds no device plane")
-        spans = [s for s in xtrace.host_spans(planes)
+        spans = [s for s in xtrace.host_spans(planes, xtrace.HOST_SPANS)
                  if s.name != "bench.window" and s.end_ns > lo and s.start_ns < hi]
         peaks = xtrace.peaks_for(device["kind"])
         records = sysm.trace_records(prof.t)
-        ctx = Context(cell, devs, spans, peaks, records)
+        # readers time the benchmark's spans; the program's name idle gaps
+        mine = [s for s in spans if s.name.startswith(xtrace.BENCH_SPANS)]
+        ctx = Context(cell, devs, mine, peaks, records)
         metrics = {}
         for m in cell.per_layer():
             v = reader(m["name"]).read(ctx)

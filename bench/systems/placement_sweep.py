@@ -12,6 +12,10 @@ Two loops, both closed (each call waits for the last):
   sweeps of the workflow on the configuration's platforms, back to back,
   each result copied to the host.
 
+The workflow is the configuration's ``workflow`` steps, listed in a
+topological order, and its ``edges`` by step name; without ``edges`` it is
+the chain in ``workflow`` order.
+
 The window closes with the first call that ends at or after ``--seconds``,
 so a rate counts all the work and all the time of the window.
 """
@@ -26,14 +30,30 @@ import numpy as np
 
 from bench import traffic
 from bench.harness import load_json, span
+from bench.sweep_reference import edges as workflow_edges
 
 SAMPLE_CALLS = {"scorer": 8, "simulate_placements": 1}  # calls the reference checks
 WARMUP_CALL = 1 << 40  # draws of the warm-up calls, apart from the window's
 
 
+def check_listing(cfg: dict):
+    """The steps have to be listed in a topological order of the edges: the
+    program draws each step's row of normals in its topological order with
+    ties broken by listing order, and the plain reference in listing
+    order, so the two orders must be one."""
+    from repro.core.graph import graph_views
+
+    steps = [s["name"] for s in cfg["workflow"]]
+    unknown = {n for e in workflow_edges(cfg) for n in e} - set(steps)
+    if unknown:
+        raise ValueError(f"edges name steps the workflow lacks: {sorted(unknown)}")
+    if list(graph_views(steps, workflow_edges(cfg))[2]) != steps:
+        raise ValueError("workflow steps are not listed in a topological order")
+
+
 def placements(cfg: dict, mix: dict) -> list:
     """The candidate placements of the mix, as lists of platform names in
-    workflow order."""
+    workflow order. No two may be alike."""
     steps = [s["name"] for s in cfg["workflow"]]
     base = [s["platform"] for s in cfg["workflow"]]
     plats = [p["name"] for p in cfg["platforms"]]
@@ -55,11 +75,27 @@ def placements(cfg: dict, mix: dict) -> list:
             pl = list(base)
             pl[1 + i % (len(steps) - 2)] = plats[i % len(plats)]
             out.append(pl)
+    elif kind == "rotate_groups":
+        # candidate i moves every step of group i (mod the groups) to
+        # platform i (mod the platforms)
+        groups = mix["placements"]["groups"]
+        unknown = {s for g in groups for s in g} - set(steps)
+        if unknown:
+            raise ValueError(f"groups name steps the workflow lacks: {sorted(unknown)}")
+        out = []
+        for i in range(count):
+            pl = list(base)
+            for s in groups[i % len(groups)]:
+                pl[steps.index(s)] = plats[i % len(plats)]
+            out.append(pl)
     else:
         raise ValueError(f"unknown placement kind {kind!r}")
     if len(out) < count:
         raise ValueError(f"{kind} gives {len(out)} placements, the mix asks {count}")
-    return out[:count]
+    out = out[:count]
+    if len({tuple(pl) for pl in out}) < count:
+        raise ValueError(f"{kind} repeats a placement among its {count}")
+    return out
 
 
 class System:
@@ -78,6 +114,7 @@ class System:
 
         t = time.perf_counter()
         cfg, mix = self.cfg, self.mix
+        check_listing(cfg)
         self.S, self.PlacementScorer = S, PlacementScorer
         self.PlacementCosts = PlacementCosts
         self.cands = placements(cfg, mix)
@@ -108,6 +145,8 @@ class System:
                 ]
                 for pl in self.cands
             ]
+            # a chain goes to the sweep as steps in order, a DAG with its edges
+            self.sweep_edges = tuple(workflow_edges(cfg)) if "edges" in cfg else None
         phases["build_s"] = time.perf_counter() - t
         # two calls: the first compiles or loads the sweep, the second shows
         # that nothing is left to compile
@@ -162,15 +201,16 @@ class System:
             )
             steps = [s["name"] for s in self.cfg["workflow"]]
             nodes = {n: None for n in steps}
-            edges = list(zip(steps, steps[1:]))
+            edges = workflow_edges(self.cfg)
             cands = [dict(zip(steps, pl)) for pl in self.cands]
-            dists = scorer.distributions(nodes, edges, cands, costs)
+            dists = scorer.distributions(nodes, edges, cands, costs,
+                                         prefetch=self.cfg["prefetch"])
             with span("bench.quantiles", self.traced):
                 q = np.quantile(dists, sc["quantile"], axis=1)
             return dists, q
         seeds = traffic.sweep_seeds(self.seed, call, mix["sweep_seeds"])
         spec = self.S.ExperimentSpec(
-            self.step_sets[0], n_requests=mix["n_requests"],
+            self.step_sets[0], edges=self.sweep_edges, n_requests=mix["n_requests"],
             interarrival_s=self.cfg["interarrival_s"], prefetch=self.cfg["prefetch"],
             seeds=seeds,
         )
@@ -218,7 +258,9 @@ class System:
 
     def trace_records(self, t_trace):
         return {"n_requests": self.mix["n_requests"],
-                "rows": self.mix["sweep_seeds"] * len(self.cands)}
+                "rows": self.mix["sweep_seeds"] * len(self.cands),
+                "nodes": len(self.cfg["workflow"]),
+                "edges": len(workflow_edges(self.cfg))}
 
     # -- after the window ---------------------------------------------------------
     def release(self):

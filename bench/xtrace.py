@@ -9,7 +9,8 @@ tests. On a TPU the device planes are named ``/device:TPU:<n>``; their
 name is the instruction's text, ``%name = shape op(...)``) and their
 ``XLA Modules`` line one event per executed program (``jit_<fn>(<id>)``).
 Host planes carry the benchmark's own ``jax.profiler.TraceAnnotation``
-spans, whose names start with ``bench.``.
+spans, whose names start with ``bench.``, and the program's spans
+(``repro.obs.span``), whose names start with ``geoff.``.
 
 Device and host timestamps share one time base, up to a skew of about a
 millisecond; host spans are used only to label idle gaps and to bound the
@@ -26,7 +27,8 @@ from dataclasses import dataclass, field
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
-SPAN_PREFIX = "bench."
+BENCH_SPANS = ("bench.",)  # the benchmark's own spans, which readers time
+HOST_SPANS = ("bench.", "geoff.")  # and the program's, which name idle gaps
 
 
 @dataclass(frozen=True)
@@ -86,8 +88,9 @@ def device_planes(planes) -> list:
     return [p for p in planes if p.name.startswith("/device:") and p.line(OPS_LINE)]
 
 
-def host_spans(planes) -> list:
-    """The benchmark's own annotations, from every host thread."""
+def host_spans(planes, prefixes=BENCH_SPANS) -> list:
+    """The annotations whose names start with one of ``prefixes``, from
+    every host thread, by start."""
     return sorted(
         (
             e
@@ -95,7 +98,7 @@ def host_spans(planes) -> list:
             if p.name.startswith("/host:")
             for ln in p.lines
             for e in ln.events
-            if e.name.startswith(SPAN_PREFIX)
+            if e.name.startswith(prefixes)
         ),
         key=lambda e: e.start_ns,
     )
@@ -260,23 +263,35 @@ def top_ops(dev: DeviceTrace, k: int = 10) -> list:
 
 def idle_gaps(dev: DeviceTrace, spans, k: int = 10) -> list:
     """The longest idle gaps of the device inside the window, each labelled
-    by the host span that overlaps it most, the innermost among those that
-    cover it whole (``host`` when none does): [[label, seconds], ...],
-    longest first. ``spans`` excludes the span that brackets the window."""
+    by what the host did for most of it: every instant of the gap goes to
+    the innermost span that covers it (the shortest, where spans nest), or
+    to ``host`` where none does, and the gap takes the name that holds the
+    most of its time: [[label, seconds], ...], longest first. ``spans``,
+    sorted by start, excludes the span that brackets the window."""
     busy = busy_intervals([e for e, _ in dev.ops], dev.lo, dev.hi)
     edges = [dev.lo] + [x for iv in busy for x in iv] + [dev.hi]
     gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)]
     gaps = sorted((g for g in gaps if g[1] > g[0]), key=lambda g: g[0] - g[1])[:k]
     out = []
     for g0, g1 in gaps:
-        best, label = (0.0, 0.0), "host"
+        inside = []
         for s in spans:
             if s.start_ns >= g1:
                 break
-            key = (min(g1, s.end_ns) - max(g0, s.start_ns), -s.dur_ns)
-            if key[0] > 0 and key > best:
-                best, label = key, s.name
-        out.append([label, (g1 - g0) * 1e-9])
+            if s.end_ns > g0:
+                inside.append(s)
+        cuts = sorted({g0, g1} | {t for s in inside for t in (s.start_ns, s.end_ns)
+                                  if g0 < t < g1})
+        held: dict = {}
+        cover, j = [], 0  # the spans that cover the piece [a, b]
+        for a, b in zip(cuts, cuts[1:]):
+            while j < len(inside) and inside[j].start_ns <= a:
+                cover.append(inside[j])
+                j += 1
+            cover = [s for s in cover if s.end_ns > a]
+            name = min(cover, key=lambda s: s.dur_ns).name if cover else "host"
+            held[name] = held.get(name, 0.0) + (b - a)
+        out.append([max(held, key=held.get), (g1 - g0) * 1e-9])
     return out
 
 

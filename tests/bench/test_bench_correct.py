@@ -37,17 +37,25 @@ def run(cell, seed=SEED, seconds=1.0, control=False):
 
 
 # -- the placement sweep ---------------------------------------------------------
-def sweep_cell(name):
-    m = harness.mix(name.split(".")[1])
+def small(m):
+    """A sweep mix at a size a test run can hold."""
     if m["call"] == "scorer":
         fewer = dict(m["placements"], count=8)
-        m = dict(m, n_requests=64, sweep_seeds=2, placements=fewer)
-    else:
-        m = dict(m, n_requests=2048)
-    return harness.Cell(SPEC, name, mix_=m)
+        return dict(m, n_requests=64, sweep_seeds=2, placements=fewer)
+    return dict(m, n_requests=2048)
 
 
-SWEEPS = ["fig4-paper.decide", "fig4-paper.throughput"]
+def sweep_cell(name):
+    return harness.Cell(SPEC, name, mix_=small(harness.Cell(SPEC, name).mix))
+
+
+def is_scorer(name):
+    return harness.Cell(SPEC, name).mix["call"] == "scorer"
+
+
+# every cell whose configuration the placement sweep runs
+SWEEPS = [w["name"] for w in SPEC["workloads"]
+          if harness.config(w["config"])["system"] == "placement_sweep"]
 
 
 @pytest.mark.parametrize("name", SWEEPS)
@@ -67,21 +75,19 @@ def test_sweep_control_fails_the_limit(name):
         line = run(sweep_cell(name), seed=seed, control=True)
         assert line["correct"] is False
         numbers = {k: c for k, c in line["checks"].items() if k != "window_compiles"}
-        assert len(numbers) == (2 if name.endswith("decide") else 1)
+        assert len(numbers) == (2 if is_scorer(name) else 1)
         for c in numbers.values():
             assert c["value"] > 3 * c["limit"], line["checks"]
 
 
-FAULTS = [
-    ("fig4-paper.decide", "answer"),
-    ("fig4-paper.decide", "half_batch"),
-    ("fig4-paper.throughput", "answer"),
-    ("fig4-paper.throughput", "half_batch"),
-    # the scorer's platforms never go cold, so only the throughput cell
-    # carries the cold scan's state
-    ("fig4-paper.throughput", "cold_state_unchanged"),
-    ("fig4-paper.throughput", "cold_flipped_late"),
-]
+def sweep_faults(scorer):
+    # the scorer's platforms never go cold, so only a sweep of
+    # ``simulate_placements`` carries the cold scan's state
+    cold = [] if scorer else ["cold_state_unchanged", "cold_flipped_late"]
+    return ["answer", "half_batch"] + cold
+
+
+FAULTS = [(name, f) for name in SWEEPS for f in sweep_faults(is_scorer(name))]
 
 
 @pytest.mark.parametrize("name,fault", FAULTS)

@@ -103,11 +103,64 @@ def test_flash_attention_reader_uses_the_call_shapes():
 
 
 def test_cold_scan_reader_counts_unpadded_bytes():
-    planes = plane([Event(COLD, 0, 1000), Event(COLD, 2000, 1000)], [])
-    (dev,) = xtrace.device_traces(planes, 0, 1e6)
-    ctx = Context(None, [dev], [], PEAKS, {"rows": 8, "n_requests": 512})
-    want = 100 * 2 * (8 * 8 * 512 / PEAKS["hbm_bytes_per_s"]) / 2e-6
+    # one whole sweep of 4 nodes, one kernel call per node, and a second
+    # sweep cut by the window's end: every call in the window counts
+    modules = [Event("jit__sweep(1)", 0, 9000), Event("jit__sweep(2)", 9500, 2000)]
+    ops = [Event(COLD, 2000 * i, 1000) for i in range(4)] + [Event(COLD, 9800, 1000)]
+    (dev,) = xtrace.device_traces(plane(ops, modules), 0, 10_000)
+    records = {"rows": 8, "n_requests": 512, "nodes": 4, "edges": 3}
+    ctx = Context(None, [dev], [], PEAKS, records)
+    want = 100 * 5 * (8 * 8 * 512 / PEAKS["hbm_bytes_per_s"]) / 5e-6
     assert reader("cold_scan_roofline.decide").read(ctx) == pytest.approx(want)
+
+
+def old_cold_scan_reader(ctx):
+    """The reader as it was while the sweep made one kernel call per node:
+    each call the work of every (seed, placement) row of one node."""
+    from bench import flops
+
+    calls = ctx.devices[0].kernel_events("cold_scan")
+    work = flops.cold_scan_work(ctx.records["rows"], ctx.records["n_requests"])
+    share = xtrace.roofline_share([work] * len(calls),
+                                  sum(e.dur_ns for e, _ in calls) * 1e-9, ctx.peaks)
+    return None if share is None else share[0]
+
+
+@pytest.mark.parametrize("rows,requests,nodes", [(256, 512, 4), (8, 2**20, 4),
+                                                 (8, 131072, 64)])
+def test_cold_scan_reader_is_the_old_one_at_one_call_per_node(rows, requests, nodes):
+    sweeps = [Event(f"jit__sweep({i})", 100_000 * i, 90_000) for i in range(3)]
+    ops = [Event(COLD, 100_000 * i + 1000 * v + 7, 611 + 13 * v)
+           for i in range(3) for v in range(nodes)]
+    (dev,) = xtrace.device_traces(plane(ops, sweeps), 0, 1e6)
+    records = {"rows": rows, "n_requests": requests, "nodes": nodes, "edges": nodes - 1}
+    ctx = Context(None, [dev], [], PEAKS, records)
+    new = reader("cold_scan_roofline.throughput").read(ctx)
+    assert new == old_cold_scan_reader(ctx)  # exactly, not approximately
+
+
+def test_cold_scan_reader_spreads_a_sweep_over_grouped_calls():
+    # a program that groups a 64-node DAG's 3 levels into 3 calls a sweep:
+    # each call carries a third of the sweep's work, not one node's
+    sweeps = [Event("jit__sweep(1)", 0, 9000), Event("jit__sweep(2)", 10_000, 9000)]
+    ops = [Event(COLD, 10_000 * i + 2000 * c, 1000) for i in range(2) for c in range(3)]
+    (dev,) = xtrace.device_traces(plane(ops, sweeps), 0, 1e6)
+    records = {"rows": 8, "n_requests": 131072, "nodes": 64, "edges": 76}
+    ctx = Context(None, [dev], [], PEAKS, records)
+    sweep_bytes = 2 * 4 * 64 * 8 * 131072
+    want = 100 * (2 * sweep_bytes / PEAKS["hbm_bytes_per_s"]) / 6e-6
+    assert reader("cold_scan_roofline.throughput").read(ctx) == pytest.approx(want)
+    assert old_cold_scan_reader(ctx) == pytest.approx(want * 3 / 64)
+
+
+def test_cold_scan_reader_reads_nothing_without_a_whole_sweep():
+    records = {"rows": 8, "n_requests": 512, "nodes": 4, "edges": 3}
+    cut = [Event("jit__sweep(1)", 500, 2000)]  # runs past the window's end
+    for modules, ops in (([], [Event(COLD, 0, 1000)]), (cut, [Event(COLD, 600, 100)]),
+                         ([Event("jit__sweep(1)", 0, 500)], [])):
+        (dev,) = xtrace.device_traces(plane(ops, modules), 0, 1000)
+        ctx = Context(None, [dev], [], PEAKS, records)
+        assert reader("cold_scan_roofline.decide").read(ctx) is None
 
 
 def test_idle_gaps_take_the_innermost_host_span():
@@ -116,6 +169,39 @@ def test_idle_gaps_take_the_innermost_host_span():
     (dev,) = xtrace.device_traces(planes, 0, 1000)
     gaps = xtrace.idle_gaps(dev, spans)
     assert gaps == [["bench.quantiles", pytest.approx(110e-9)]]
+
+
+def sweep_spans(t, build, dispatch, wait, fetch):
+    """One sweep's benchmark span and the program's phases inside it."""
+    phases = [("geoff.sweep.build", build), ("geoff.sweep.dispatch", dispatch),
+              ("geoff.sweep.wait", wait), ("geoff.sweep.fetch", fetch)]
+    total = build + dispatch + wait + fetch
+    out = [Event("bench.sweep", t, total), Event("geoff.sweep", t, total)]
+    for name, dur in phases:
+        out.append(Event(name, t, dur))
+        t += dur
+    return out
+
+
+def test_idle_gaps_name_the_programs_phases():
+    # two sweeps: build 500, dispatch 20, device busy through the wait,
+    # fetch 100; the gap between them is fetch, then the next build and
+    # dispatch: most of it is the build, though no phase covers it whole
+    spans = sweep_spans(0, 500, 20, 300, 100) + sweep_spans(920, 500, 20, 300, 60)
+    spans = sorted(spans + [Event("bench.window", 0, 2000)], key=lambda e: e.start_ns)
+    ops = [op("a", 520, 300), op("b", 1440, 300)]
+    planes = plane(ops, [], [s for s in spans if s.name != "bench.window"])
+    bench_only = {s.name for s in xtrace.host_spans(planes)}
+    assert bench_only == {"bench.window", "bench.sweep"}
+    found = xtrace.host_spans(planes, xtrace.HOST_SPANS)
+    assert {s.name for s in found} >= {"geoff.sweep.build", "geoff.sweep.fetch"}
+    (dev,) = xtrace.device_traces(planes, 0, 1800)
+    gaps = xtrace.idle_gaps(dev, [s for s in found if s.name != "bench.window"])
+    assert gaps == [["geoff.sweep.build", pytest.approx(620e-9)],
+                    ["geoff.sweep.build", pytest.approx(520e-9)],
+                    ["geoff.sweep.fetch", pytest.approx(60e-9)]]
+    # a stretch that no span covers is the host's
+    assert xtrace.idle_gaps(dev, [])[0] == ["host", pytest.approx(620e-9)]
 
 
 def test_scorer_host_time_pairs_sweeps_with_decisions():
