@@ -2,8 +2,9 @@
 
 One compiled program sweeps (seeds x placements x requests): every ``Dist``
 draw is pre-sampled as a device array, the node-major
-poke/payload/prepare/start/end recurrence runs as ``jax.lax.scan`` over the
-topo order under ``jit``, and the whole thing is ``vmap``-ed twice — over
+poke/payload/prepare/start/end recurrence runs level by level over the
+graph's topological levels under ``jit`` (the nodes of one level are
+independent rows), and the whole thing is ``vmap``-ed twice — over
 candidate placements (same graph, different platforms/medians) and over
 seeds. That is what lets ``PlacementScorer`` score an entire candidate set
 in one jitted call and the benches sweep seeds x placements without a
@@ -25,19 +26,24 @@ marginal cost is just the recurrence. Marginals are the same lognormals
 as the numpy backends — medians/p99 agree within 1%
 (tests/test_jaxsim.py, the jaxsim bench).
 
-Three structural observations make the compiled program fast on a single
+Four structural observations make the compiled program fast on a single
 core (and they are exactly the levers the numpy path pulls, batched):
 
 - the poke cascade is draw-free and uniform over requests — ``poke[v]``
   is ``t0 + depth(v) * msg_latency`` where ``depth`` is a static
   shortest-hop count through poke-enabled nodes, so it is precomputed on
-  the host per placement instead of carried through the scan;
+  the host per placement instead of carried through the recurrence;
 - the lognormal factor ``exp(sigma * z)`` only depends on sigma, and a
   placement set reuses a handful of sigmas, so factors are tabulated per
   (seed, distinct sigma) and gathered per placement — sampling cost is
   per SEED, not per (seed x placement);
-- the cold-start recurrence (the one sequential piece) is the
-  ``kernels/cold_scan.py`` Pallas kernel on TPU and its log-depth
+- the payload join reads the graph's edge list, a level at a time, padded
+  only to that level's own largest in-degree, so a request's reads scale
+  with the edges, not with nodes x the widest in-degree;
+- the cold-start recurrence (the one sequential piece) runs once per
+  topological level over all of the level's nodes: the
+  ``kernels/cold_scan.py`` Pallas kernel on TPU, whose lanes then hold
+  every (seed, placement, node) row of the level, and its log-depth
   GF(2)-affine parallel scan everywhere else, whose ``while_loop`` gate
   exits immediately in regimes where no request's status depends on its
   predecessor — the batched analogue of the numpy scan's candidate list.
@@ -53,6 +59,7 @@ sampling, exactly like the numpy path.
 from __future__ import annotations
 
 import math
+from dataclasses import dataclass
 from functools import partial
 from typing import NamedTuple
 
@@ -60,21 +67,40 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.cold_scan import cold_scan_parallel
+from repro.kernels.cold_scan import cold_scan_parallel, kernel_lanes
 from repro.kernels.ops import cold_scan as cold_scan_kernel
 from repro.obs.trace import span
 
 
-class _Graph(NamedTuple):
-    """Structure shared by every placement: topology + drift scale arrays."""
+class _Level(NamedTuple):
+    """One topological level: its nodes (rows into topo order, in listing
+    order), each node's in-edges (ids into the edge list), and which of
+    its nodes are sinks (positions in the level). Level 0 holds the
+    sources, the only nodes without in-edges."""
 
-    pred_idx: jax.Array  # (V, maxP) int32 rows into topo order (0-padded)
-    pred_mask: jax.Array  # (V, maxP) bool — which slots are real edges
-    is_source: jax.Array  # (V,) bool
-    is_sink: jax.Array  # (V,) bool
-    compute_scale: jax.Array  # (n_platforms, n) drift masks (ones w/o drift)
+    nodes: tuple
+    edges: tuple  # per node, a tuple of edge ids
+    sinks: tuple
+
+
+@partial(
+    jax.tree_util.register_dataclass,
+    data_fields=("compute_scale", "transfer_scale", "fetch_scale"),
+    meta_fields=("edge_src", "edge_dst", "levels"),
+)
+@dataclass(frozen=True)
+class _Graph:
+    """Structure shared by every placement: the drift scale arrays and,
+    static (part of the compiled program's key, fixed by the workflow),
+    the edge list and its partition into topological levels."""
+
+    compute_scale: jax.Array  # (n_platforms, n) drift masks ((n_platforms, 1)
+    #   ones, never read, without drift)
     transfer_scale: jax.Array  # (n_platforms, n)
     fetch_scale: jax.Array  # (n_platforms, n)
+    edge_src: tuple  # (E,) rows into topo order, grouped by destination
+    edge_dst: tuple  # (E,)
+    levels: tuple  # of _Level, longest path from a source
 
 
 class _Sigmas(NamedTuple):
@@ -98,15 +124,15 @@ class _Placement(NamedTuple):
     compute_sig: jax.Array  # (V,)
     poke_depth: jax.Array  # (V,) hops from a source via poke-enabled nodes
     #   (0.0 at sources, +inf where the cascade never reaches)
-    transfer: jax.Array  # (V, maxP) per-edge payload FIRST-byte transfer
+    transfer: jax.Array  # (E,) per-edge payload FIRST-byte transfer
     #   (== the whole-object transfer when streaming is off)
-    transfer_last: jax.Array  # (V, maxP) per-edge LAST-byte transfer
+    transfer_last: jax.Array  # (E,) per-edge LAST-byte transfer
     #   (only read by the recurrence when use_stream; == transfer otherwise)
     plat_idx: jax.Array  # (V,) int32 rows into the drift scale arrays
     fault_extra: jax.Array  # (V, n) per-(node, request) retry-backoff
     #   seconds from the host-precomputed fault plane ((V, 1) zeros and
     #   never read when use_faults is off — the hash-based plane needs no
-    #   device rng, so it rides the scan like the drift masks do)
+    #   device rng, so it rides the recurrence like the drift masks do)
 
 
 def use_pallas() -> bool:
@@ -115,10 +141,59 @@ def use_pallas() -> bool:
 
 
 def _cold_mask(t0s, warm_end, cold_end, keep_warm, use_pallas):
-    # under _sweep's two vmaps each (1, n) row joins one kernel call's lanes
+    """The cold mask of one level's (L, n) rows, each row with its node's
+    ``keep_warm`` (L,). Under _sweep's two vmaps the Pallas path's rows of
+    every (seed, placement) join one kernel call's lanes."""
+    keep_warm = keep_warm[:, None]
     if use_pallas:
-        return cold_scan_kernel(t0s, warm_end[None, :], cold_end[None, :], keep_warm)[0]
+        return cold_scan_kernel(t0s, warm_end, cold_end, keep_warm)
     return cold_scan_parallel(t0s, warm_end, cold_end, keep_warm)
+
+
+def _contiguous(idx):
+    return np.array_equal(idx.ravel(), np.arange(idx.flat[0], idx.flat[0] + idx.size))
+
+
+def _rows(x, idx):
+    """Rows ``idx`` of ``x``, shaped like ``idx``. Static ints (any shape)
+    take a slice where they run in order without a gap, else a gather; a
+    traced index (a scan step's) always gathers."""
+    if not isinstance(idx, np.ndarray):
+        return x[idx]
+    if _contiguous(idx):
+        lo = int(idx.flat[0])
+        return x[lo:lo + idx.size].reshape(idx.shape + x.shape[1:])
+    return x[idx]
+
+
+def _runs(levels):
+    """The levels grouped into runs of consecutive levels of one width and
+    one largest in-degree; the sources (in-degree 0) join the run after
+    them where the widths agree. A run of several levels steps as one
+    ``lax.scan``, so a chain is one scanned body however deep, and the
+    program grows with the number of runs, not with the depth."""
+    runs = []
+    for lv in levels:
+        width, degree = len(lv.nodes), max(map(len, lv.edges))
+        if runs and runs[-1][0] == width and runs[-1][1] in (degree, 0):
+            runs[-1] = (width, degree, runs[-1][2] + (lv,))
+        else:
+            runs.append((width, degree, (lv,)))
+    return [run for _, _, run in runs]
+
+
+def _join_table(run):
+    """The run's in-edges padded to its largest in-degree D: (R, L, D) edge
+    ids and which slots are real (None when every slot is). Padding
+    repeats a node's first edge, or edge 0 at a source."""
+    width = max(len(e) for lv in run for e in lv.edges)
+    eids = np.array(
+        [[e + (e[:1] or (0,)) * (width - len(e)) for e in lv.edges] for lv in run],
+        np.int32,
+    ).reshape(len(run), len(run[0].nodes), width)
+    degree = np.array([[len(e) for e in lv.edges] for lv in run])
+    real = np.arange(width) < degree[..., None]
+    return eids, (None if real.all() else real)
 
 
 def _simulate_one(
@@ -126,102 +201,94 @@ def _simulate_one(
     use_pallas, use_stream, use_faults, sample_idx=None,
 ):
     """One (seed, placement) request stream: the node-major recurrence of
-    ``_run_graph_vectorized`` as a scan over topo order. ``factors`` are
-    the seed's three lognormal tables ``exp(sigma_u * z)``, each (U, V, n).
-    Returns the (n,) per-request totals — plus, when ``sample_idx`` (a
-    (k,) request-index array) is given, the per-node scan ys at those
-    columns (payload, effective cold, fetch, compute, end; each (V, k)) so
-    the host can rebuild ``obs`` traces for the sampled requests. The
-    gather rides the existing scan outputs: the totals arithmetic is
+    ``_run_graph_vectorized``, one step per topological level, each step
+    over all of the level's nodes at once. A run of levels of one shape
+    (``_runs``) steps as a scan; a level alone is traced as it is.
+    ``factors`` are the seed's three lognormal tables ``exp(sigma_u * z)``,
+    each (U, V, n); a level takes its nodes' rows of the draws by node
+    index, so the draws stay one row per node in topo order. Returns the
+    (n,) per-request totals — plus, when ``sample_idx`` (a (k,)
+    request-index array) is given, the per-node values at those columns
+    (payload, effective cold, fetch, compute, end; each (V, k) in topo
+    order) so the host can rebuild ``obs`` traces for the sampled requests.
+    The gather rides the recurrence's values: the totals arithmetic is
     untouched, and no extra randomness is drawn."""
     f_cold, f_fetch, f_compute = factors
     V, n = f_cold.shape[1:]
     dtype = t0s.dtype
-    rows = jnp.arange(V)
+    plat = placed.plat_idx
 
-    def draws(table, sig_idx, median):
+    def draws(table, sig_idx, median, scale=None):
         # select each node's factor row by its sigma index. The table's U
         # axis is static and tiny (distinct sigmas across the placement
         # set), so an unrolled where-chain beats a general gather — under
-        # the double vmap a gather lowers to per-element loads on CPU.
+        # the placement vmap a gather would batch into a slow scatter-ish op
         factor = table[0]
         for u in range(1, table.shape[0]):
             factor = jnp.where((sig_idx == u)[:, None], table[u], factor)
-        return median[:, None] * factor  # (V, n)
+        draw = median[:, None] * factor
+        if scale is not None:
+            # drift rescales AFTER sampling (the draw-neutral contract)
+            draw = draw * scale[plat]
+        # a degenerate dist (median <= 0) draws 0. The select also keeps
+        # each draw one rounded value where a sum below reads it: a fused
+        # multiply-add would round product and sum once, and the totals
+        # would move off the numpy backend's by an ulp
+        return jnp.where(median[:, None] > 0, draw, 0)  # (V, n)
 
     cold = draws(f_cold, placed.cold_sig, placed.cold_median)
-    fetch = draws(f_fetch, placed.fetch_sig, placed.fetch_median)
-    compute = draws(f_compute, placed.compute_sig, placed.compute_median)
-    transfer = placed.transfer[:, :, None]  # (V, maxP, 1)
-    transfer_last = placed.transfer_last[:, :, None] if use_stream else None
+    fetch = draws(f_fetch, placed.fetch_sig, placed.fetch_median,
+                  graph.fetch_scale if use_drift else None)
+    compute = draws(f_compute, placed.compute_sig, placed.compute_median,
+                    graph.compute_scale if use_drift else None)
+    transfer = placed.transfer[:, None]  # (E, 1)
+    transfer_last = placed.transfer_last[:, None] if use_stream else None
+    edge_src = np.array(graph.edge_src, np.int32)
     if use_drift:
-        # drift rescales AFTER sampling (the draw-neutral contract); a
-        # degraded platform slows every link it terminates (max endpoint)
-        compute = compute * graph.compute_scale[placed.plat_idx]
-        fetch = fetch * graph.fetch_scale[placed.plat_idx]
-        tr_dst = graph.transfer_scale[placed.plat_idx]  # (V, n)
-        tr_src = graph.transfer_scale[placed.plat_idx[graph.pred_idx]]
-        tr_sc = jnp.maximum(tr_src, tr_dst[:, None, :])
-        transfer = transfer * tr_sc
+        # a degraded platform slows every link it terminates (max
+        # endpoint); a free link stays free, and a rounded value, as above
+        tr_sc = jnp.maximum(
+            graph.transfer_scale[plat[edge_src]],
+            graph.transfer_scale[plat[np.array(graph.edge_dst, np.int32)]],
+        )  # (E, n)
+        transfer = jnp.where(transfer > 0, transfer * tr_sc, 0)
         if use_stream:
-            transfer_last = transfer_last * tr_sc
+            transfer_last = jnp.where(transfer_last > 0, transfer_last * tr_sc, 0)
 
     inf = jnp.array(jnp.inf, dtype)
-    xs = (
-        rows,
-        graph.pred_idx,
-        graph.pred_mask,
-        graph.is_source,
-        graph.is_sink,
-        placed.poke_depth,
-        placed.keep_warm,
-        cold,
-        fetch,
-        compute,
-        jnp.broadcast_to(transfer, (V,) + transfer.shape[1:]),
-    )
-    if use_stream:
-        xs = xs + (
-            jnp.broadcast_to(transfer_last, (V,) + transfer_last.shape[1:]),
-        )
-    if use_faults:
-        xs = xs + (placed.fault_extra,)
+    source_payload = t0s + msg / 2  # the request's own message
 
-    def body(end_all, x):
-        # use_stream / use_faults are static: the traced program is
-        # literally unchanged when they are False (no extra scan inputs,
-        # no extra ops) — unpacked in reverse append order
-        if use_faults:
-            *x, fault_extra_v = x
-        if use_stream:
-            *x, tr_last_v = x
-        (
-            v,
-            pidx,
-            pmask,
-            is_src,
-            is_sink,
-            depth,
-            kw,
-            cold_v,
-            fetch_v,
-            compute_v,
-            tr_v,
-        ) = x
-        # payload join (max over in-edges of upstream end + transfer);
-        # with streaming the join gates on FIRST bytes and the last bytes
-        # bound the compute tail below
-        arrivals = jnp.where(pmask[:, None], end_all[pidx] + tr_v, -inf)
-        payload = jnp.where(is_src, t0s + msg / 2, jnp.max(arrivals, axis=0))
-        if use_stream:
-            arrivals_last = jnp.where(
-                pmask[:, None], end_all[pidx] + tr_last_v, -inf
-            )
-            payload_last = jnp.where(
-                is_src, t0s + msg / 2, jnp.max(arrivals_last, axis=0)
-            )
+    def step(end_all, sink_end, lv, contiguous):
+        """One level. ``lv`` holds the level's values and its structure:
+        static numpy where the level is traced alone, traced where it is a
+        step of a run's scan. use_stream / use_faults are static: the
+        traced program is literally unchanged when they are False (no
+        extra inputs, no extra ops)."""
+        cold_v, fetch_v, compute_v = lv["cold"], lv["fetch"], lv["compute"]
+        if "src" not in lv:
+            # a level of sources alone
+            payload = jnp.broadcast_to(source_payload, cold_v.shape)
+        else:
+            # payload join (max over in-edges of upstream end + transfer);
+            # with streaming the join gates on FIRST bytes and the last
+            # bytes bound the compute tail below
+            src_end = _rows(end_all, lv["src"])  # (L, D, n)
+
+            def join(tr):
+                arrivals = src_end + tr
+                if "real" in lv:
+                    arrivals = jnp.where(lv["real"][:, :, None], arrivals, -inf)
+                arrival = jnp.max(arrivals, axis=1)
+                if "is_source" in lv:
+                    arrival = jnp.where(lv["is_source"], source_payload, arrival)
+                return arrival
+
+            payload = join(lv["tr"])
+            if use_stream:
+                payload_last = join(lv["tr_last"])
         # start/end under both cold hypotheses, then the cold scan
         if prefetch:
+            depth = lv["depth"][:, None]
             poke_v = t0s + depth * msg
             poked = jnp.isfinite(depth)
             warm_start = jnp.where(
@@ -239,10 +306,12 @@ def _simulate_one(
             cold_start = warm_start + cold_v
         warm_end = warm_start + compute_v
         cold_end = cold_start + compute_v
-        if use_stream:
+        if use_stream and "src" in lv:
             # per-chunk pipeline tail (closed form, matching the numpy
             # path); sources have no in-edges, so their tail never binds
-            tail = jnp.where(is_src, -inf, payload_last + compute_v * inv_chunks)
+            tail = payload_last + compute_v * inv_chunks
+            if "is_source" in lv:
+                tail = jnp.where(lv["is_source"], -inf, tail)
             warm_end = jnp.maximum(warm_end, tail)
             cold_end = jnp.maximum(cold_end, tail)
         if use_faults:
@@ -251,28 +320,88 @@ def _simulate_one(
             # ordering of the scalar and numpy paths. Exhausted budgets
             # are applied HOST-side to the totals (inf would poison the
             # cold recurrence), so the compiled sweep stays finite.
-            warm_end = warm_end + fault_extra_v
-            cold_end = cold_end + fault_extra_v
-        mask = _cold_mask(t0s, warm_end, cold_end, kw, use_pallas)
+            warm_end = warm_end + lv["fault"]
+            cold_end = cold_end + lv["fault"]
+        mask = _cold_mask(t0s, warm_end, cold_end, lv["keep_warm"], use_pallas)
         end_v = jnp.where(mask, cold_end, warm_end)
-        sink_row = jnp.where(is_sink, end_v, -inf)
+        nodes, sinks = lv["nodes"], lv["sinks"]
+        if contiguous:
+            end_all = jax.lax.dynamic_update_slice_in_dim(end_all, end_v, nodes[0], 0)
+        else:
+            end_all = end_all.at[nodes].set(end_v)
+        if isinstance(sinks, np.ndarray):
+            if sinks.any():
+                last = jnp.max(_rows(end_v, np.flatnonzero(sinks)), axis=0)
+                sink_end = jnp.maximum(sink_end, last)
+        else:
+            last = jnp.max(jnp.where(sinks[:, None], end_v, -inf), axis=0)
+            sink_end = jnp.maximum(sink_end, last)
+        sampled = None
         if sample_idx is not None:
             cold_eff = jnp.where(mask, cold_v, jnp.zeros_like(cold_v))
-            sampled = (
-                payload[sample_idx],
-                cold_eff[sample_idx],
-                fetch_v[sample_idx],
-                compute_v[sample_idx],
-                end_v[sample_idx],
+            sampled = tuple(
+                a[:, sample_idx]
+                for a in (payload, cold_eff, fetch_v, compute_v, end_v)
             )
-            return end_all.at[v].set(end_v), (sink_row, sampled)
-        return end_all.at[v].set(end_v), sink_row
+        return end_all, sink_end, sampled
 
-    _, ys = jax.lax.scan(body, jnp.zeros((V, n), dtype), xs)
-    if sample_idx is not None:
-        sink_ends, sampled = ys
-        return jnp.max(sink_ends, axis=0) - t0s, sampled
-    return jnp.max(ys, axis=0) - t0s
+    end_all = jnp.zeros((V, n), dtype)
+    sink_end = jnp.full((n,), -inf, dtype)
+    sampled = []
+    for run in _runs(graph.levels):
+        # the run's values, (R, L, ...): a level takes its nodes' rows
+        nodes = np.array([lv.nodes for lv in run], np.int32)
+        eids, real = _join_table(run)
+        xs = {
+            "cold": _rows(cold, nodes),
+            "fetch": _rows(fetch, nodes),
+            "compute": _rows(compute, nodes),
+            "keep_warm": _rows(placed.keep_warm, nodes),
+            "nodes": nodes,
+            "sinks": np.array([np.isin(np.arange(len(lv.nodes)), lv.sinks)
+                               for lv in run]),
+        }
+        if prefetch:
+            xs["depth"] = _rows(placed.poke_depth, nodes)
+        if use_faults:
+            xs["fault"] = _rows(placed.fault_extra, nodes)
+        if eids.shape[-1]:
+            xs["src"] = edge_src[eids]
+            xs["tr"] = _rows(transfer, eids)
+            if use_stream:
+                xs["tr_last"] = _rows(transfer_last, eids)
+            if real is not None:
+                xs["real"] = real
+            if not run[0].edges[0]:
+                xs["is_source"] = np.arange(len(run)) == 0
+        contiguous = all(_contiguous(row) for row in nodes)
+        if len(run) == 1:
+            end_all, sink_end, ys = step(
+                end_all, sink_end, {k: v[0] for k, v in xs.items()}, contiguous
+            )
+            ys = None if ys is None else tuple(y[None] for y in ys)
+        else:
+            no_sinks = None
+            if not xs["sinks"].any():
+                no_sinks = xs.pop("sinks")[0]
+
+            def body(carry, x, no_sinks=no_sinks, contiguous=contiguous):
+                if no_sinks is not None:
+                    x["sinks"] = no_sinks
+                end_all, sink_end, ys = step(*carry, x, contiguous)
+                return (end_all, sink_end), ys
+
+            (end_all, sink_end), ys = jax.lax.scan(body, (end_all, sink_end), xs)
+        if ys is not None:
+            sampled.append(tuple(y.reshape((-1,) + y.shape[2:]) for y in ys))
+    totals = sink_end - t0s
+    if sample_idx is None:
+        return totals
+    # levels in order, then back to topo order
+    where = np.argsort(np.concatenate([lv.nodes for lv in graph.levels]))
+    return totals, tuple(
+        _rows(jnp.concatenate(parts), where) for parts in zip(*sampled)
+    )
 
 
 @partial(
@@ -286,9 +415,9 @@ def _sweep(
     *, prefetch, use_drift, use_pallas, use_stream, use_faults,
 ):
     """(seeds, placements, requests) totals in one compiled program. With
-    ``sample_idx``, also the sampled per-node ys pytree (leaves gain the
-    (seeds, placements) leading axes)."""
-    V = graph.pred_idx.shape[0]
+    ``sample_idx``, also the sampled per-node values pytree (leaves gain
+    the (seeds, placements) leading axes)."""
+    V = placed.cold_median.shape[-1]
     n = t0s.shape[0]
     f32 = jnp.float32
 
@@ -334,6 +463,36 @@ def _poke_depths(order, steps, preds):
     return np.array([depth[v] for v in order])
 
 
+def graph_levels(order, preds):
+    """The partition of the nodes (rows into ``order``, a topological
+    order) into topological levels: a node's level is its longest path
+    from a source. Each level lists its nodes in topo order. A sweep makes
+    one cold-scan call per level."""
+    pos = {v: i for i, v in enumerate(order)}
+    depth = []
+    for v in order:
+        depth.append(max((depth[pos[u]] + 1 for u in preds[v]), default=0))
+    return [
+        tuple(i for i, d in enumerate(depth) if d == lv)
+        for lv in range(max(depth, default=-1) + 1)
+    ]
+
+
+def kernel_counters(graph, rows):
+    """The Pallas path's counters of one sweep of ``rows`` (seed,
+    placement) rows: ``levels``, its kernel calls (one per topological
+    level, scanned or not); ``edges``, the in-edge slots the join reads per
+    request, padding included; ``kernel_lanes``, the lane width of the
+    widest call; ``kernel_lanes_total``, the lanes summed over the calls."""
+    lanes = [kernel_lanes(rows * len(lv.nodes)) for lv in graph.levels]
+    return {
+        "levels": len(lanes),
+        "edges": sum(_join_table(run)[0].size for run in _runs(graph.levels)),
+        "kernel_lanes": max(lanes),
+        "kernel_lanes_total": sum(lanes),
+    }
+
+
 def _build(
     sim, order, step_sets, preds, succs, t0s, drift, dtype, stream=None,
     faults=None, retry=None,
@@ -345,8 +504,8 @@ def _build(
     unchanged.
 
     With a ``FaultSchedule``, each placement also gets its (V, n)
-    retry-backoff plane (``_Placement.fault_extra``, a scan input like the
-    drift masks) and a (n,) request-failed mask; the planes come from the
+    retry-backoff plane (``_Placement.fault_extra``, read like the drift
+    masks) and a (n,) request-failed mask; the planes come from the
     same hash-based ``FaultSchedule.plane`` the scalar and numpy backends
     price, so all three agree bit-for-bit. Returns ``(placed, sigmas,
     graph, fault_failed)`` with ``fault_failed`` a (P, n) bool array (all
@@ -354,20 +513,27 @@ def _build(
     f64 = dtype
     V = len(order)
     n = len(t0s)
-    max_p = max([1] + [len(preds[v]) for v in order])
-    idx_of = {v: i for i, v in enumerate(order)}
-    pred_idx = np.zeros((V, max_p), np.int32)
-    pred_mask = np.zeros((V, max_p), bool)
-    for i, v in enumerate(order):
-        for j, u in enumerate(preds[v]):
-            pred_idx[i, j] = idx_of[u]
-            pred_mask[i, j] = True
-    is_source = np.array([not preds[v] for v in order])
-    is_sink = np.array([not succs[v] for v in order])
+    pos = {v: i for i, v in enumerate(order)}
+    # the edge list, once per graph: grouped by destination in topo order,
+    # each destination's in-edges in its preds order
+    edges = [(pos[u], i) for i, v in enumerate(order) for u in preds[v]]
+    in_edges = [[] for _ in order]
+    for e, (_, i) in enumerate(edges):
+        in_edges[i].append(e)
+    levels = tuple(
+        _Level(
+            nodes=nodes,
+            edges=tuple(tuple(in_edges[i]) for i in nodes),
+            sinks=tuple(j for j, i in enumerate(nodes) if not succs[order[i]]),
+        )
+        for nodes in graph_levels(order, preds)
+    )
 
     plat_names = list(sim.platforms)
     plat_row = {name: i for i, name in enumerate(plat_names)}
-    scales = np.ones((3, len(plat_names), n), f64)
+    # without drift the program never reads the scales: a column of ones
+    # stands in for the (3, platforms, n) planes
+    scales = np.ones((3, len(plat_names), n if drift is not None else 1), f64)
     if drift is not None:
         ks = np.arange(n)
         for name in plat_names:
@@ -386,8 +552,8 @@ def _build(
             "compute_median": np.empty(V, f64),
             "compute_sigma": np.empty(V, f64),
             "poke_depth": _poke_depths(order, steps, preds).astype(f64),
-            "transfer": np.zeros((V, max_p), f64),
-            "transfer_last": np.zeros((V, max_p), f64),
+            "transfer": np.zeros(len(edges), f64),
+            "transfer_last": np.zeros(len(edges), f64),
             "plat_idx": np.zeros(V, np.int32),
             "fault_extra": np.zeros((V, n if faults_on else 1), f64),
             "fault_failed": np.zeros(n, bool),
@@ -410,12 +576,12 @@ def _build(
             row["compute_median"][i] = step.compute.median
             row["compute_sigma"][i] = step.compute.sigma
             row["plat_idx"][i] = plat_row[step.platform]
-            for j, u in enumerate(preds[v]):
-                # routes through the table-aware per-edge resolver, so a
-                # calibrated transfer_table is honored on this backend too
-                first, last = sim._pair_transfer_fl(steps[u], step)
-                row["transfer"][i, j] = first
-                row["transfer_last"][i, j] = last
+        for e, (u, i) in enumerate(edges):
+            # routes through the table-aware per-edge resolver, so a
+            # calibrated transfer_table is honored on this backend too
+            first, last = sim._pair_transfer_fl(steps[order[u]], steps[order[i]])
+            row["transfer"][e] = first
+            row["transfer_last"][e] = last
         return row
 
     # _transfer_fl reads sim.stream; pin it to THIS call's config for the
@@ -460,20 +626,51 @@ def _build(
     )
     fault_failed = np.stack([r["fault_failed"] for r in all_rows])
     graph = _Graph(
-        pred_idx,
-        pred_mask,
-        is_source,
-        is_sink,
         compute_scale=scales[0],
         transfer_scale=scales[1],
         fetch_scale=scales[2],
+        edge_src=tuple(u for u, _ in edges),
+        edge_dst=tuple(i for _, i in edges),
+        levels=levels,
     )
     return placed, sigmas, graph, fault_failed
 
 
+def _resident(sim, args):
+    """``args`` for the sweep, with the leaves a simulator sweeps again on
+    the device. ``sim`` keeps its last sweep's inputs: a leaf whose bytes
+    equal the last one's (in a tree of the same structure) is sent to the
+    device once and then reused, so a simulator that sweeps one workflow
+    again under new seeds sends only the seeds' keys. A leaf seen for the
+    first time goes to the sweep as it is, in the jit's one batched
+    transfer, as before."""
+    leaves, tree = jax.tree.flatten(args)
+    last = getattr(sim, "_jax_resident", None)
+    if last is None or last[0] != tree:
+        last = (tree, [None] * len(leaves), [None] * len(leaves))
+    seen = [b is not None and _same_bytes(a, b) for a, b in zip(leaves, last[1])]
+    dev = [d if s else None for s, d in zip(seen, last[2])]
+    send = [i for i, (s, d) in enumerate(zip(seen, dev)) if s and d is None]
+    if send:
+        for i, d in zip(send, jax.device_put([leaves[i] for i in send])):
+            dev[i] = d
+    sim._jax_resident = (tree, leaves, dev)
+    return jax.tree.unflatten(
+        tree, [a if d is None else d for a, d in zip(leaves, dev)]
+    )
+
+
+def _same_bytes(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    if a.dtype != b.dtype or a.shape != b.shape:
+        return False
+    word = f"u{a.dtype.itemsize}"  # bits, not values: -0.0 is not 0.0
+    return np.array_equal(a.reshape(-1).view(word), b.reshape(-1).view(word))
+
+
 def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
                 drift=None, dtype=np.float64, sample_idx=None, stream=None,
-                faults=None, retry=None, build=None):
+                faults=None, retry=None, build=None, sweep=None):
     """The jax backend's one entry point: simulate every (seed, placement)
     pair of one workflow graph in a single compiled call.
 
@@ -506,7 +703,7 @@ def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
 
     ``faults`` / ``retry``: optional ``FaultSchedule`` / ``RetryPolicy``.
     The hash-based fault plane is precomputed host-side per placement —
-    another plane riding the scan next to the cold-start inputs (a static
+    another plane read next to the cold-start inputs (a static
     ``use_faults`` branch, program unchanged when off) — and exhausted
     retry budgets turn the affected requests' totals into ``inf`` after
     the sweep (the compiled recurrence itself stays finite). The fault
@@ -520,7 +717,12 @@ def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
     which ends here where the sweep is dispatched, with the ``host_bytes``
     handed to the sweep as its counter. The phases after it are the
     ``geoff.sweep.dispatch``, ``.wait`` and ``.fetch`` spans, the last
-    with the ``fetched_bytes`` read back.
+    with the ``fetched_bytes`` read back. ``sweep``: the caller's open
+    ``geoff.sweep`` span, which gets ``kernel_counters`` on the Pallas path.
+
+    ``sim`` keeps the inputs it sweeps again on the device (``_resident``):
+    a capacity planner's replays of one workflow under new seeds send only
+    the seeds' keys.
     """
     if drift is None:
         drift = sim.drift
@@ -549,7 +751,7 @@ def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
     dtype = np.dtype(dtype).type
     # the recurrence only changes when first != last bytes is possible;
     # chunks=1 (even with P2P rerouting the transfer VALUES) keeps the
-    # whole-object scan — first == last there, so the tail never binds
+    # whole-object recurrence — first == last there, so the tail never binds
     use_stream = stream is not None and stream.chunks > 1
     use_faults = faults is not None and bool(faults)
     with jax.enable_x64(True):
@@ -568,20 +770,21 @@ def run_batched(sim, order, step_sets, preds, succs, t0s, prefetch, seeds,
             placed,
             sigmas,
             graph,
-            jnp.asarray(np.asarray(t0s, dtype)),
-            jnp.asarray(dtype(sim.msg)),
-            jnp.asarray(dtype(1.0 / stream.chunks) if use_stream else dtype(1.0)),
-            jnp.asarray(np.asarray(sample_idx, np.int32))
-            if sample_idx is not None
-            else None,
+            np.array(t0s, dtype),
+            dtype(sim.msg),
+            dtype(1.0 / stream.chunks) if use_stream else dtype(1.0),
+            np.array(sample_idx, np.int32) if sample_idx is not None else None,
         )
+        if sweep is not None and sweep.record is not None and use_pallas():
+            sweep.set(**kernel_counters(graph, len(seeds) * len(step_sets)))
         if build is not None:
             if build.record is not None:
                 build.set(host_bytes=sum(a.nbytes for a in jax.tree.leaves(args)))
             build.end()
         with span("geoff.sweep.dispatch"):
             out = _sweep(
-                *args,
+                keys,
+                *_resident(sim, args[1:]),
                 prefetch=bool(prefetch),
                 use_drift=drift is not None,
                 use_pallas=use_pallas(),

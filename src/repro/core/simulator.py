@@ -1265,12 +1265,15 @@ class WorkflowSimulator:
 
         Under a profiler session the call records the ``geoff.sweep`` span
         (counters ``rows``, ``requests`` and, where the sweep runs the
-        Pallas cold scan, ``kernel_lanes``: the lane width of each node's
-        one kernel call), tiled by its ``build``,
-        ``dispatch``, ``wait`` and ``fetch`` phases and, with ``_tracer``,
-        ``traces`` (``repro.obs.span``)."""
+        Pallas cold scan, ``jaxsim.kernel_counters``: ``levels``, the
+        kernel calls it makes, one per topological level, each over every
+        (seed, placement) row of the level's nodes; ``edges``, the in-edge
+        slots the join reads per request, padding included;
+        ``kernel_lanes``, the lane width of the widest call; and
+        ``kernel_lanes_total``, the lanes summed over the calls), tiled by its
+        ``build``, ``dispatch``, ``wait`` and ``fetch`` phases and, with
+        ``_tracer``, ``traces`` (``repro.obs.span``)."""
         from repro.core import jaxsim  # deferred: jax pays init cost
-        from repro.kernels.cold_scan import kernel_lanes
         from repro.obs.trace import span
 
         with span("geoff.sweep", requests=spec.n_requests) as sweep:
@@ -1296,8 +1299,6 @@ class WorkflowSimulator:
                 seeds = spec.seeds if spec.seeds is not None else (self.seed,)
                 rows = len(seeds) * len(placements)
                 sweep.set(rows=rows)
-                if jaxsim.use_pallas():
-                    sweep.set(kernel_lanes=kernel_lanes(rows))
                 drift = spec.drift if spec.drift is not None else self.drift
                 stream = spec.stream if spec.stream is not None else self.stream
                 faults = spec.faults if spec.faults is not None else self.faults
@@ -1318,6 +1319,7 @@ class WorkflowSimulator:
                     self, order, step_sets, preds, succs, t0s, spec.prefetch,
                     list(seeds), drift=drift, dtype=dtype, sample_idx=sample_idx,
                     stream=stream, faults=faults, retry=retry, build=build,
+                    sweep=sweep,
                 )
             if _tracer is None:
                 return out

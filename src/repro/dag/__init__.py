@@ -20,5 +20,6 @@ from repro.dag.sim import (  # noqa: F401
     DagTrace,
     DagWorkflowSimulator,
     document_dag_fig4,
+    genome_dag_1chr,
     serialize_chain,
 )

@@ -45,3 +45,37 @@ def document_dag_fig4():
         ("ocr", "e_mail"),
     ]
     return steps, edges
+
+
+GENOME_POPULATIONS = ("AFR", "AMR", "EAS", "EUR", "GBR", "SAS", "ALL")
+
+
+def genome_dag_1chr(chunks: int = 48):
+    """The 1000Genome workflow of one chromosome
+    (github.com/pegasus-isi/1000genome-workflow): ``chunks`` parallel
+    ``individuals`` steps fan in to ``individuals_merge``; ``sifting`` is a
+    second source; per population a ``mutation_overlap`` and a
+    ``frequency`` sink each read both the merge and ``sifting``. At 48
+    chunks: 64 steps, 76 edges, 3 topological levels, a 48-way join.
+    Steps are listed in a topological order; the costs are set, in the
+    proportions of the workflow's task types, not calibrated."""
+    individuals = [f"individuals_{i:02d}" for i in range(chunks)]
+    data = "lambda-eu-central-1"
+    steps = [
+        SimStep(n, data, compute=Dist(3.2, 0.25), fetch=Dist(0.8, 0.3))
+        for n in individuals
+    ]
+    steps += [
+        SimStep("sifting", data, compute=Dist(1.1, 0.15), fetch=Dist(0.6, 0.3)),
+        SimStep("individuals_merge", data, compute=Dist(2.4, 0.15),
+                fetch=Dist(0.3, 0.3)),
+    ]
+    edges = [(n, "individuals_merge") for n in individuals]
+    for kind, compute in (("mutation_overlap", 1.6), ("frequency", 3.0)):
+        for pop in GENOME_POPULATIONS:
+            sink = f"{kind}_{pop}"
+            steps.append(
+                SimStep(sink, "gcf", compute=Dist(compute, 0.2), fetch=Dist(0.3, 0.3))
+            )
+            edges += [("individuals_merge", sink), ("sifting", sink)]
+    return steps, edges

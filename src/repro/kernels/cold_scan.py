@@ -129,6 +129,8 @@ def _scan_rows(code, *, chunk, block_b, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        # the kernel's instruction in the device trace, under any transform
+        name="cold_scan",
     )(codep)
     return mask[:T, :B].T > 0
 

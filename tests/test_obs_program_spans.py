@@ -197,7 +197,9 @@ def test_sweep_span_counts_the_kernel_lanes_on_the_pallas_path(
     tmp_path, monkeypatch, n_seeds, n_placements, lanes
 ):
     """With the Pallas cold scan (interpret mode here) every (seed,
-    placement) row joins one kernel call per node, whose lane width the
+    placement) row joins one kernel call per topological level (the Fig-4
+    chain: four levels of one node, scanned as one run whose join reads
+    one in-edge slot per node, the source's masked), whose lane width the
     ``geoff.sweep`` span counts: whole 128-lane blocks."""
     monkeypatch.setattr(jaxsim, "use_pallas", lambda: True)
     sim = S.WorkflowSimulator(S.paper_platforms(), seed=0)
@@ -211,4 +213,36 @@ def test_sweep_span_counts_the_kernel_lanes_on_the_pallas_path(
     assert out.shape == (n_seeds, n_placements, 16)
     assert sweep.attrs == {
         "requests": 16, "rows": n_seeds * n_placements, "kernel_lanes": lanes,
+        "levels": 4, "edges": 4, "kernel_lanes_total": 4 * lanes,
     }
+
+
+@pytest.mark.parametrize(
+    "n_seeds,n_placements,lanes,total", [(1, 1, 128, 384), (2, 4, 512, 768)]
+)
+def test_sweep_span_counts_levels_edges_and_lanes_of_a_wide_dag(
+    tmp_path, monkeypatch, n_seeds, n_placements, lanes, total
+):
+    """The 1000Genome workflow of one chromosome: 64 nodes in three levels
+    (49 sources, the merge, 14 sinks) and 76 edges. Each level's call
+    holds rows x its nodes in whole 128-lane blocks: at 2 x 4 rows 392,
+    8 and 112 rows in 512 + 128 + 128 lanes, two thirds of them used."""
+    from repro.dag import genome_dag_1chr
+
+    monkeypatch.setattr(jaxsim, "use_pallas", lambda: True)
+    steps, edges = genome_dag_1chr()
+    sim = S.WorkflowSimulator(S.paper_platforms(), seed=0)
+    spec = S.ExperimentSpec(steps, edges=edges, n_requests=16,
+                            seeds=tuple(range(n_seeds)))
+    clear_program_spans()
+    with jax.profiler.trace(str(tmp_path)):
+        sim.simulate_placements(spec, [steps] * n_placements)
+    (sweep,) = program_spans("geoff.sweep")
+    clear_program_spans()
+    rows = n_seeds * n_placements
+    assert sweep.attrs == {
+        "requests": 16, "rows": rows, "kernel_lanes": lanes,
+        "levels": 3, "edges": 76, "kernel_lanes_total": total,
+    }
+    if rows == 8:
+        assert rows * 64 / total == pytest.approx(2 / 3)

@@ -15,6 +15,7 @@ process may load the TPU library, and every test worker imports this file.
 import functools
 import os
 import re
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -120,20 +121,27 @@ def test_rmsnorm_compiles(one_chip):
     )
 
 
-def compile_sweep(sharding, monkeypatch, n_seeds, n_placements, n, dtype):
+def compile_sweep(sharding, monkeypatch, n_seeds, n_placements, n, dtype,
+                  placements=None, edges=None):
     """The whole ``_sweep`` with the kernel path forced and compiled: on the
     CPU the kernel would otherwise pick interpret mode while tracing, and
     the program would hold no kernel. Forced through a jit named
     ``cold_scan``, as ``ops.cold_scan`` is: the kernel's instruction takes
-    the name, and the device trace's reader finds the kernel by it."""
+    the name, and the device trace's reader finds the kernel by it. The
+    workflow is the Fig-4 chain's candidates unless ``placements`` (and a
+    DAG's ``edges``) are given."""
     forced = functools.partial(cold_scan, interpret=False)
     monkeypatch.setattr(
         jaxsim, "cold_scan_kernel", jax.jit(functools.wraps(cold_scan)(forced))
     )
-    placements = candidate_placements(n_placements)
+    if placements is None:
+        placements = candidate_placements(n_placements)
     sim = S.WorkflowSimulator(S.paper_platforms(), seed=0)
-    order, _, preds, succs = _spec_graph(placements[0], None)
-    step_sets = [dict(enumerate(p)) for p in placements]
+    order, _, preds, succs = _spec_graph(placements[0], edges)
+    if edges is None:
+        step_sets = [dict(enumerate(p)) for p in placements]
+    else:
+        step_sets = [{s.name: s for s in p} for p in placements]
     with jax.enable_x64(True):
         placed, sigmas, graph, _ = jaxsim._build(
             sim, order, step_sets, preds, succs, np.arange(n) * 1.0, None, dtype
@@ -156,6 +164,23 @@ def compile_sweep(sharding, monkeypatch, n_seeds, n_placements, n, dtype):
             *args, None, prefetch=True, use_drift=False, use_pallas=True,
             use_stream=False, use_faults=False,
         ).compile()
+
+
+def kernel_calls(compiled):
+    """The program's ``cold_scan`` kernel calls, as the (requests, lanes)
+    shape of each one's code plane."""
+    kernels = [
+        line for line in compiled.as_text().splitlines() if xtrace.is_kernel(line)
+    ]
+    assert all(
+        xtrace.base_name(xtrace.instr_name(k)) == "cold_scan" for k in kernels
+    )
+    planes = [
+        re.findall(r"operand_layout_constraints=\{s32\[([0-9,]*)\]", k)
+        for k in kernels
+    ]
+    assert all(len(p) == 1 for p in planes)
+    return [tuple(int(d) for d in p[0].split(",")) for p in planes]
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float64])
@@ -192,3 +217,44 @@ def test_sweep_folds_its_rows_into_one_cold_scan_call(
     assert tuple(int(d) for d in operand.split(",")) == (-(-n // 256) * 256, lanes)
     if n == 2**20:
         assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
+
+
+def test_genome_sweep_makes_one_cold_scan_call_per_level(one_chip, monkeypatch):
+    """The 1000Genome workflow of one chromosome (64 steps, 3 levels) at
+    the replay mix's 2 seeds x 4 placements: three kernel calls, the 49
+    sources' 392 rows in 512 lanes, the merge's 8 rows and the 14 sinks'
+    112 rows in 128 each; 768 lanes, where one call per node took 64 x
+    128. The request count is cut to keep the compile short: it sets each
+    plane's length, not its lanes."""
+    from repro.dag import genome_dag_1chr
+
+    steps, edges = genome_dag_1chr()
+    plats = [p.name for p in S.paper_platforms()]
+    cands = [
+        [replace(s, platform=p) if s.name[:12] == "individuals_"
+         and s.name != "individuals_merge" else s for s in steps]
+        for p in plats
+    ]
+    n = 2048
+    compiled = compile_sweep(one_chip, monkeypatch, 2, 4, n, np.float32,
+                             placements=cands, edges=edges)
+    calls = kernel_calls(compiled)
+    assert sorted(calls) == [(n, 128), (n, 128), (n, 512)]
+    assert sum(lanes for _, lanes in calls) == 768
+
+
+def test_a_deep_chain_scans_its_levels_through_one_cold_scan_call(
+    one_chip, monkeypatch
+):
+    """A chain's levels are one run of one width: the program scans them
+    through one kernel instruction, as deep as the chain is, so a 64-step
+    chain compiles to the 4-step chain's one call and not to 64."""
+    chain = [
+        S.SimStep(f"step_{i:02d}", "gcf", compute=S.Dist(0.5, 0.2),
+                  fetch=S.Dist(0.1, 0.3))
+        for i in range(64)
+    ]
+    n = 2048
+    compiled = compile_sweep(one_chip, monkeypatch, 2, 4, n, np.float32,
+                             placements=[chain] * 4)
+    assert kernel_calls(compiled) == [(n, 128)]
